@@ -41,11 +41,11 @@ def run(smoke: bool = False) -> None:
         ops.bellman_backup_batched(hb, pmfb, tailb, hso)), repeat=3)
     emit("kernel_bellman_batched_17x192x33", us, f"flops/call={N * flops:.2e}")
 
-    # interpret vs lowered: on a real TPU/GPU the Mosaic/Triton lowering is
-    # *validated* against interpret mode (identical inputs, max |diff|); on
-    # the CPU CI box there is no lowering, so the case records the skip —
-    # a TPU run of this benchmark is the acceptance check for the kernel.
-    if jax.default_backend() in ("tpu", "gpu"):
+    # interpret vs lowered: on a TPU the Mosaic lowering is *validated*
+    # against interpret mode (identical inputs, max |diff|); elsewhere there
+    # is no lowering, so the case records the skip (tests/test_tpu_compile.py
+    # compiles the kernel for a described v5e on every test run).
+    if jax.default_backend() == "tpu":
         lowered = ops.bellman_backup(h, pmfs, tails, 1.0, interpret=False)
         interp = ops.bellman_backup(h, pmfs, tails, 1.0, interpret=True)
         diff = float(jnp.max(jnp.abs(lowered - interp)))

@@ -26,6 +26,10 @@ def main() -> None:
                     help="reduced mode for every benchmark (CI-sized)")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (
         appE_structure_breaks,
         degraded_frontier,

@@ -31,7 +31,9 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
+import jax.scipy.linalg  # noqa: F401  (lu_factor / lu_solve)
 import numpy as np
 
 from .smdp import BatchedSMDP, ModulatedBatchedSMDP, TruncatedSMDP
@@ -417,6 +419,54 @@ def policy_matrix_banded_modulated(
     return sc_flat[:, None] * m_flat + jnp.diag(1.0 - sc_flat)
 
 
+#: cap on the float64 residual-refinement sweeps of solve_refined (LAPACK
+#: dsgesv's ITERMAX); a system that has not converged by then is returned
+#: as NaN
+REFINE_MAX_ITER = 30
+
+
+def solve_refined(a, b):
+    """x = a^{-1} b for one (S, S) system, without a float64 factorization.
+
+    TPUs implement LU for float32 only, so a float64 system is factored
+    once in float32 and its solution refined by float64 residual sweeps,
+    x += LU32^{-1} (b - a x) (mixed-precision iterative refinement, as in
+    LAPACK dsgesv), until the residual meets dsgesv's backward-error test
+    ``|r| <= |x| |a| eps64 sqrt(S)`` (max-norms).  A converged solution is
+    as accurate as a float64 LU's.  A system too ill-conditioned for the
+    float32 factor (cond(a) * eps32 >~ 1) does not converge and comes back
+    non-finite — exactly how a singular float64 solve surfaces to callers.
+    float32 systems are solved directly.
+    """
+    # a TPU would otherwise run the factor's and residual's matmuls as bf16
+    with jax.default_matmul_precision("highest"):
+        if a.dtype != jnp.float64:
+            return jnp.linalg.solve(a, b[..., None])[..., 0]
+        lu_piv = jax.scipy.linalg.lu_factor(a.astype(jnp.float32))
+        eps64 = np.finfo(np.float64).eps
+        tol = jnp.max(jnp.sum(jnp.abs(a), axis=1)) * eps64 * np.sqrt(len(b))
+
+        def step(x, r):  # one refinement sweep: x + LU32^-1 r, new residual
+            dx = jax.scipy.linalg.lu_solve(lu_piv, r.astype(jnp.float32))
+            x = x + dx.astype(jnp.float64)
+            return x, b - a @ x
+
+        def ok(x, r):
+            return jnp.max(jnp.abs(r)) <= jnp.max(jnp.abs(x)) * tol
+
+        def cond(carry):
+            i, x, r = carry
+            return (i < REFINE_MAX_ITER) & ~ok(x, r) & jnp.all(jnp.isfinite(x))
+
+        def body(carry):
+            i, x, r = carry
+            return (i + 1, *step(x, r))
+
+        x, r = step(jnp.zeros_like(b), b)
+        _, x, r = jax.lax.while_loop(cond, body, (0, x, r))
+    return jnp.where(ok(x, r), x, jnp.nan)
+
+
 def policy_eval_linear(c_pi, m_pi, ref_state: int = 0):
     """Exact average-cost evaluation of a frozen policy: solve for (g, h).
 
@@ -425,12 +475,12 @@ def policy_eval_linear(c_pi, m_pi, ref_state: int = 0):
     slot of the pinned unknown: A = (I - M_pi) with column ``ref_state``
     replaced by ones.  Unichain policies give a nonsingular A; a multichain
     (or otherwise degenerate) policy surfaces as non-finite output, which
-    the MPI safeguard in rvi.py rejects.
+    the MPI safeguard in rvi.py rejects.  The solve is solve_refined.
     """
     S = c_pi.shape[0]
     a = jnp.eye(S, dtype=c_pi.dtype) - m_pi
     a = a.at[:, ref_state].set(1.0)
-    x = jnp.linalg.solve(a, c_pi[..., None])[..., 0]
+    x = solve_refined(a, c_pi)
     g = x[ref_state]
     h = x.at[ref_state].set(0.0)
     return g, h

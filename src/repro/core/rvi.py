@@ -79,6 +79,11 @@ from .smdp import SMDPSpec, TruncatedSMDP, build_smdp, phase_rho
 #: accel="auto" decision (sweep_solve and the modulated loops).
 ACCEL_RHO_THRESHOLD = 0.5
 
+#: every solver contraction runs at full precision: a TPU otherwise feeds
+#: float32 matmuls to the MXU as bfloat16, which the f32 lockstep phase
+#: cannot converge through
+_HI = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass
 class RVIResult:
@@ -98,7 +103,7 @@ class RVIResult:
 
 def dense_backup(c_tilde: jnp.ndarray, m_tilde: jnp.ndarray, h: jnp.ndarray):
     """Q(s,a) = c~(s,a) + sum_j m~(j|s,a) h(j); infeasible entries are +inf."""
-    return c_tilde + jnp.einsum("saj,j->sa", m_tilde, h)
+    return c_tilde + jnp.einsum("saj,j->sa", m_tilde, h, precision=_HI)
 
 
 def banded_backup(
@@ -127,7 +132,7 @@ def banded_backup(
     valid = j <= s_max
     hwin = jnp.where(valid, h[jnp.minimum(j, s_max)], 0.0)
     # G[t, a] = sum_k pmfs[a, k] hwin[t, k]  -> correlation as a matmul (MXU!)
-    G = hwin @ pmfs.T  # (T, A)
+    G = jnp.matmul(hwin, pmfs.T, precision=_HI)  # (T, A)
     G = G + tails.T * h[S - 1]  # overflow mass towards S_o
     # scatter to (S, A): for state s and action a, base t = s_val(s) - a
     s_val = jnp.minimum(jnp.arange(S), s_max)  # S_o behaves as s_max
@@ -770,6 +775,23 @@ def _span(diff):
     return jnp.max(diff, axis=-1) - jnp.min(diff, axis=-1)
 
 
+def _solve_spd_small(a, b):
+    """x with a x = b for tiny (..., M, M) symmetric positive-definite a.
+
+    Unrolled Gauss-Jordan elimination (no pivoting needed for SPD): only
+    elementwise ops, so a float64 system stays float64 on every backend —
+    TPUs have no float64 LU — and a Tikhonov-regularized Anderson Gram
+    matrix too ill-conditioned for a float32 factor still solves.
+    """
+    M = a.shape[-1]
+    for k in range(M):
+        f = a[..., :, k] / a[..., k, k][..., None]  # (..., M)
+        f = f.at[..., k].set(0.0)  # row k stays as the pivot row
+        a = a - f[..., :, None] * a[..., k, None, :]
+        b = b - f * b[..., k, None]
+    return b / jnp.diagonal(a, axis1=-2, axis2=-1)
+
+
 @partial(jax.jit, static_argnames=("max_iter", "s_max", "backup_kind", "period"))
 def _rvi_loop_batched_mpi(
     c_tilde,
@@ -938,14 +960,16 @@ def _rvi_loop_batched_anderson(
         # (empty history -> gamma = 0 -> the candidate is the plain step)
         vm = valid[..., None]
         rm = jnp.where(vm, dr, 0.0)  # (N, M, S)
-        gram = jnp.einsum("nms,nks->nmk", rm, rm)
-        rhs = jnp.einsum("nms,ns->nm", rm, r)
+        gram = jnp.einsum("nms,nks->nmk", rm, rm, precision=_HI)
+        rhs = jnp.einsum("nms,ns->nm", rm, r, precision=_HI)
         tr = jnp.trace(gram, axis1=-2, axis2=-1)
         lam = (reg * tr / M + 1e-30)[:, None, None] * jnp.eye(
             M, dtype=c_tilde.dtype
         )
-        gamma = jnp.linalg.solve(gram + lam, rhs[..., None])[..., 0]  # (N, M)
-        h_cand = h_pl - jnp.einsum("nm,nms->ns", gamma, jnp.where(vm, dh, 0.0) + rm)
+        gamma = _solve_spd_small(gram + lam, rhs)  # (N, M)
+        h_cand = h_pl - jnp.einsum(
+            "nm,nms->ns", gamma, jnp.where(vm, dh, 0.0) + rm, precision=_HI
+        )
         h_cand = h_cand - h_cand[:, ref_state][:, None]  # pin the gauge
         hb_c, g_c = bell(h_cand)
         r_c = hb_c - h_cand
@@ -1302,13 +1326,13 @@ def banded_backup_modulated(
     hwin = jnp.where(valid[None], h[:, jnp.minimum(j, s_max)], 0.0)  # (K,T,Kb)
     # G[z, t, a] = sum_{w, k} pmfs[a, z, w, k] hwin[w, t, k]  (phase-coupled
     # correlation; the K = 1 slice is exactly banded_backup's hwin @ pmfs.T)
-    G = jnp.einsum("azwk,wtk->zta", pmfs, hwin)
-    G = G + jnp.einsum("azwt,w->zta", tails, h[:, S - 1])
+    G = jnp.einsum("azwk,wtk->zta", pmfs, hwin, precision=_HI)
+    G = G + jnp.einsum("azwt,w->zta", tails, h[:, S - 1], precision=_HI)
     s_val = jnp.minimum(jnp.arange(S), s_max)
     base = jnp.clip(s_val[:, None] - jnp.arange(A)[None, :], 0, s_max)  # (S,A)
     mh_serve = G[:, base, jnp.arange(A)[None, :]]  # (K, S, A)
     nxt = jnp.where(jnp.arange(S) < s_max, jnp.arange(S) + 1, S - 1)
-    mh_wait = wait_m @ h[:, nxt]  # (K, S)
+    mh_wait = jnp.matmul(wait_m, h[:, nxt], precision=_HI)  # (K, S)
     mh = mh_serve.at[:, :, 0].set(mh_wait)
     return c_tilde + scale * mh + (1.0 - scale) * h[:, :, None]
 

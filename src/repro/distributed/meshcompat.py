@@ -1,90 +1,45 @@
-"""Version-tolerant wrappers around JAX's mesh-context APIs.
+"""The JAX mesh-context calls this repo uses, in one place.
 
-The mesh-context surface moved between JAX releases: newer versions expose
-``jax.sharding.get_abstract_mesh`` / ``jax.sharding.set_mesh`` /
-``jax.sharding.AxisType``; the pinned 0.4.x series keeps the first two under
-``jax._src.mesh`` (where the unset abstract-mesh context is a bare ``()``
-sentinel rather than an empty ``AbstractMesh``) and has no ``AxisType`` at
-all.  Every mesh-context consumer in this repo goes through this module so
-the version probing lives in exactly one place.
+Every mesh-context consumer goes through this module, so a change in
+JAX's mesh surface is absorbed here.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def get_abstract_mesh():
     """The ambient AbstractMesh, or ``None`` when no mesh context is set."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        am = getter()
-    else:
-        from jax._src import mesh as _mesh_impl
-
-        am = _mesh_impl.get_abstract_mesh()
-    if not isinstance(am, AbstractMesh) or not am.axis_names:
-        return None
-    return am
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.axis_names else None
 
 
 def set_mesh(mesh: Mesh):
     """Context manager installing ``mesh`` as the ambient (abstract) mesh."""
-    setter = getattr(jax.sharding, "set_mesh", None) or getattr(
-        jax, "set_mesh", None
-    )
-    if setter is not None:
-        return setter(mesh)
-    # 0.4.x: combine the thread-resources context (what
-    # with_sharding_constraint's bare PartitionSpecs resolve against) with
-    # the abstract-mesh context (what hint()/collectives read).  The
-    # internal jax._src.mesh.set_mesh is deliberately NOT used here: it
-    # also flips the experimental sharding_in_types flag, which breaks
-    # jax.random on this release.
-    return _legacy_set_mesh(mesh)
-
-
-@contextlib.contextmanager
-def _legacy_set_mesh(mesh: Mesh):
-    from jax._src import mesh as _mesh_impl
-
-    am = getattr(mesh, "abstract_mesh", None)
-    with contextlib.ExitStack() as stack:
-        stack.enter_context(mesh)
-        if am is not None and hasattr(_mesh_impl, "set_abstract_mesh"):
-            stack.enter_context(_mesh_impl.set_abstract_mesh(am))
-        yield mesh
+    return jax.sharding.set_mesh(mesh)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, axis_types=(axis_type.Auto,) * len(axis_names)
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with the pre-0.5 experimental fallback."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
+    """``jax.shard_map`` without the varying-manual-axes type check.
 
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    The per-device bodies here (lane-sharded event scans, explicit
+    collectives) start their scan carries from replicated constants and
+    make them device-varying inside the loop, which the check rejects
+    although every output is well defined per device.
+    """
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def abstract_mesh(axis_shapes, axis_names) -> AbstractMesh:
-    """Construct an AbstractMesh across both constructor generations.
-
-    Newer JAX takes ``AbstractMesh((("data", 16), ("model", 16)))``-style
-    (name, size) pairs; older releases took ``AbstractMesh(shape, names)``.
-    """
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
+    """An AbstractMesh of the given axis sizes and names."""
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))

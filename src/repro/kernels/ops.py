@@ -17,10 +17,7 @@ from . import decode_attention as _decode
 from . import flash_attention as _flash
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+_auto_interpret = _bellman.auto_interpret
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -33,13 +30,11 @@ def _bellman_jit(h_main, pmfs, tails, h_overflow, interpret=True):
 def bellman_backup(h_main, pmfs, tails, h_overflow, interpret: Optional[bool] = None):
     """Banded RVI backup G[t,a] (see kernels/bellman.py).
 
-    The bellman kernels resolve ``interpret=None`` via their own
-    backend-aware default (lowered on TPU *and* GPU — the kernel is a plain
-    tiled matmul loop — interpret on CPU).
+    ``interpret=None`` lowers the kernel on TPU and interprets it elsewhere.
     """
     return _bellman_jit(
         h_main, pmfs, tails, jnp.asarray(h_overflow, jnp.float32),
-        interpret=_bellman.auto_interpret(interpret),
+        interpret=_auto_interpret(interpret),
     )
 
 
@@ -56,7 +51,7 @@ def bellman_backup_batched(
     """Spec-batched banded RVI backup G[n,t,a] (see kernels/bellman.py)."""
     return _bellman_batched_jit(
         h_main, pmfs, tails, jnp.asarray(h_overflow, jnp.float32),
-        interpret=_bellman.auto_interpret(interpret),
+        interpret=_auto_interpret(interpret),
     )
 
 

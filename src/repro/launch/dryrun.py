@@ -287,10 +287,7 @@ def run_cell(
             mem["temp_size_in_bytes"] // 2,
             mem["temp_size_in_bytes"] - artifact,
         )
-        cost_raw = compiled.cost_analysis() or {}
-        if isinstance(cost_raw, (list, tuple)):  # pre-0.5 returns [dict]
-            cost_raw = cost_raw[0] if cost_raw else {}
-        cost = dict(cost_raw)
+        cost = dict(compiled.cost_analysis() or {})
         cost = {k: float(v) for k, v in cost.items() if isinstance(v, (int, float))}
         colls = parse_collectives(hlo_text)
         # XLA's cost_analysis counts while bodies ONCE; the trip-count-aware

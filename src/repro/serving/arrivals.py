@@ -551,7 +551,12 @@ def poisson_times_jax(key, lam: float, n: int):
     import jax.numpy as jnp
 
     gaps = jax.random.exponential(key, (n,), dtype=jnp.float64) / lam
-    return jnp.cumsum(gaps)
+
+    def add(t, gap):  # a sequential running sum: np.cumsum's order, and
+        t = t + gap  # unlike jnp.cumsum it compiles in seconds on TPU
+        return t, t
+
+    return jax.lax.scan(add, jnp.zeros((), jnp.float64), gaps)[1]
 
 
 def mmpp2_times_jax(key, mmpp: "MMPP2", n_steps: int, with_phases: bool = False):

@@ -403,7 +403,7 @@ def _scan_core(
          ad_min_gap, ad_init_est, ad_state0) = adap
 
     def step(carry, _):
-        (t, n_srv, n_adm, n_bat, n_eps, n_used, done), ad, qm = carry
+        (t, n_srv, n_adm, n_bat, n_eps, n_used, done, energy), ad, qm = carry
         active = jnp.logical_not(done) & (n_eps < max_eps)
         # arrivals due by `now` are admitted before any decision is taken,
         # up to _ADMIT_W per step (they are a prefix of the sorted window;
@@ -517,6 +517,9 @@ def _scan_core(
         t_next = jnp.where(wait, nxt, jnp.where(serve, t_done, t))
         if qman:
             qm = (adm_idx, head + e + a, tail, last_adm, n_shd, n_exp + e)
+            consumed = head + e + a  # queue items resolved so far
+        else:
+            consumed = n_srv + a  # request slots served so far
         carry = ((
             t_next,
             n_srv + a,
@@ -525,17 +528,18 @@ def _scan_core(
             n_eps + dec_eff.astype(i64),
             n_used + active.astype(i64),
             done | term,
+            # energy accrues in the carry, one serve at a time, so every
+            # kernel (and the Python references) sums it in event order
+            energy + zeta[a],  # zeta[0] forced to 0 by the wrappers
         ), ad, qm)
-        # (a > 0) <=> serve, so the aggregate path only needs (a, t_done) —
-        # energy is summed from a_seq after the scan; the decision flag is
-        # recorded only for the equivalence harness
+        # (a > 0) <=> serve, so the aggregate path only needs (a, t_done)
+        # and the running resolved count; the decision flag is recorded
+        # only for the equivalence harness
         a32 = a.astype(jnp.int32)
-        if qman:
-            e32 = e.astype(jnp.int32)
-            return carry, (
-                (a32, e32, dec_eff, t_done) if record else (a32, e32, t_done)
-            )
-        return carry, ((a32, dec_eff, t_done) if record else (a32, t_done))
+        c32 = consumed.astype(jnp.int32)
+        return carry, (
+            (a32, c32, dec_eff, t_done) if record else (a32, c32, t_done)
+        )
 
     zero = jnp.asarray(0, dtype=i64)
     qm0 = (
@@ -554,38 +558,33 @@ def _scan_core(
         jnp.asarray(t0, dtype=jnp.float64),
         zero, zero, zero, zero, zero,
         jnp.asarray(False),
+        jnp.asarray(0.0, dtype=jnp.float64),
     ), ad_state0 if adaptive else None, qm0)
     carry, outs = jax.lax.scan(step, carry0, None, length=n_steps, unroll=4)
-    if qman:
-        a_seq, e_seq, tdone_seq = (
-            (outs[0], outs[1], outs[3]) if record else outs
-        )
-    else:
-        a_seq, tdone_seq = (outs[0], outs[2]) if record else outs
-    (t, n_srv, n_adm, n_bat, n_eps, n_used, done), ad_final, qm_final = carry
+    a_seq, cum_seq, tdone_seq = (
+        (outs[0], outs[1], outs[3]) if record else outs
+    )
+    scalars, ad_final, qm_final = carry
+    t, n_srv, n_adm, n_bat, n_eps, n_used, done, energy = scalars
 
     # --- vectorized per-request reconstruction (one pass, no scan) -------
-    # request slot j was completed by the serve step whose request interval
-    # [cum_a - a, cum_a) contains j.  Interval starts are strictly
-    # increasing over serve steps, so scattering each serve's step index at
-    # its interval start and taking a running max assigns every slot its
-    # completing step — O(size) instead of a per-slot binary search.
-    energy = jnp.sum(zeta[a_seq])  # zeta[0] forced to 0 by the wrappers
+    # request slot j was completed by the step whose resolved interval
+    # [cum - a, cum) contains j: the first step whose running resolved
+    # count exceeds j.  The counts are nondecreasing over steps, so that is
+    # one binary search per slot (a prefix sum or running max over the
+    # slots would do the same in O(size), but compiles for minutes on TPU)
+    slots = jnp.arange(size)
+    step_of = jnp.minimum(
+        jnp.searchsorted(cum_seq, slots.astype(jnp.int32), side="right"),
+        n_steps - 1,
+    )
+    completion = tdone_seq[step_of]
     if qman:
         # managed-queue lane: the slot space is *admission order* (the
         # adm_idx queue), and steps consume a (served) + e (expired)
         # items from its head — a step does one or the other, so a done
         # slot's covering step tells served from expired apart
         adm_idx, head, tail, last_adm, n_shd, n_exp = qm_final
-        tot = (a_seq + e_seq).astype(i64)
-        cum = jnp.cumsum(tot)
-        start = jnp.where(tot > 0, cum - tot, size)
-        mark = jnp.zeros(size, dtype=jnp.int32).at[start].max(
-            jnp.arange(n_steps, dtype=jnp.int32), mode="drop"
-        )
-        step_of = jax.lax.cummax(mark)
-        completion = tdone_seq[step_of]
-        slots = jnp.arange(size)
         arr_o = arrivals[adm_idx]
         dl_o = deadlines[adm_idx]
         valid = (slots < head) & (a_seq[step_of] > 0)  # done AND served
@@ -593,14 +592,6 @@ def _scan_core(
         lat_sum = jnp.sum(lat)
         miss = jnp.sum(valid & (completion > dl_o))
     else:
-        cum_a = jnp.cumsum(a_seq.astype(i64))
-        start = jnp.where(a_seq > 0, cum_a - a_seq, size)  # non-serves drop
-        mark = jnp.zeros(size, dtype=jnp.int32).at[start].max(
-            jnp.arange(n_steps, dtype=jnp.int32), mode="drop"
-        )
-        epoch_of = jax.lax.cummax(mark)
-        completion = tdone_seq[epoch_of]
-        slots = jnp.arange(size)
         valid = slots < n_srv
         lat = jnp.where(valid, completion - arrivals, 0.0)
         lat_sum = jnp.sum(lat)
@@ -632,7 +623,7 @@ def _scan_core(
             ad_have_last=have_last, ad_sel=sel, ad_last_switch=last_sw,
             ad_n_switches=n_sw,
         )
-    dec_seq = (outs[2] if qman else outs[1]) if record else None
+    dec_seq = outs[2] if record else None
     return (agg, (a_seq, dec_seq, lat, valid)) if record else agg
 
 
@@ -857,7 +848,7 @@ def simulate_compiled(
         if zeta is None
         else np.asarray(zeta, dtype=np.float64).copy()
     )
-    zeta_a[0] = 0.0  # a = 0 never accounts energy (kernel sums zeta[a_seq])
+    zeta_a[0] = 0.0  # a = 0 never accounts energy (the kernel adds zeta[a])
     if draws is None:
         draws = np.ones(1)
     draws = np.asarray(draws, dtype=np.float64)
@@ -1077,7 +1068,7 @@ def run_grid(
         if zeta is None
         else np.asarray(zeta, dtype=np.float64).copy()
     )
-    zeta_a[0] = 0.0  # a = 0 never accounts energy (kernel sums zeta[a_seq])
+    zeta_a[0] = 0.0  # a = 0 never accounts energy (the kernel adds zeta[a])
     if draws is None:
         draws = np.ones((arr.shape[0], 1))
     draws = np.asarray(draws, dtype=np.float64)

@@ -317,13 +317,15 @@ def _fleet_scan_core(
     drain = jnp.asarray(drain, dtype=bool)
     t_last = jnp.asarray(t_last, dtype=jnp.float64)
     c0 = jnp.sum(jnp.isfinite(q0_times), axis=1).astype(i64)  # carried queue
+    P_sub = Q0 + size  # max substream length per replica
+    key_t = jnp.int32 if (M + 1) * (P_sub + 1) < 2**31 else i64
     fcur0 = jnp.asarray(fcur0, dtype=i64)
     infl0 = jnp.asarray(infl0, dtype=i64)
 
     def step(carry, _):
         (t, n_adm, rr, ph, neps, nuse, done,
          busy, qlen, n_route, n_srv, nbat, needs,
-         fcur, rty, infl, ndrop, nshed) = carry
+         fcur, rty, infl, ndrop, nshed, energy) = carry
         idle = jnp.isinf(busy)
         down = (fcur % 2) == 1  # odd cursor parity == inside a down interval
         ia = jnp.minimum(n_adm, size - 1)
@@ -517,19 +519,18 @@ def _fleet_scan_core(
             t, n_adm, rr, ph, neps, nuse + active.astype(i64), done,
             busy, qlen, n_route, n_srv, nbat, needs,
             fcur, rty, infl, ndrop, nshed,
+            # summed in event order, as compiled._scan_core sums it
+            energy + e_out,
         )
         a32 = jnp.where(dispatch, a, 0).astype(jnp.int32)
-        # one shared mark stream for the position reconstruction: serves
-        # scatter odd values (2*step + 1), batch drops even (2*step)
-        mark_m = jnp.where(
-            serve, m_d, jnp.where(give_up, m_b, M)
-        ).astype(jnp.int32)
-        mark_pos = jnp.where(
-            serve, start, jnp.where(give_up, dbase, 0)
-        ).astype(jnp.int32)
-        out = (a32, m_dec, mark_m, mark_pos, serve, t_done, adm_idx,
-               jnp.where(due, m_r, M).astype(jnp.int32), pos_out, shed,
-               e_out)
+        # the (replica, first position) of the substream interval this step
+        # resolves — a serve or a crash drop, at most one per step — as one
+        # sort key; other steps key past every (replica, position)
+        mark_m = jnp.where(serve, m_d, jnp.where(give_up, m_b, M))
+        mark_pos = jnp.where(serve, start, jnp.where(give_up, dbase, 0))
+        key = (mark_m * (P_sub + 1) + mark_pos).astype(key_t)
+        out = (a32, m_dec, key, serve, t_done, adm_idx,
+               jnp.where(due, m_r, M).astype(jnp.int32), pos_out, shed)
         return carry, out
 
     zero = jnp.asarray(0, dtype=i64)
@@ -547,46 +548,47 @@ def _fleet_scan_core(
         jnp.asarray(needs0, dtype=bool)
         & jnp.isinf(busy0) & (infl0 == 0) & ~down_init,
         fcur0, jnp.asarray(rty0, dtype=i64), infl0, zv, zv,
+        jnp.asarray(0.0, dtype=jnp.float64),
     )
-    carry, outs = jax.lax.scan(step, carry0, None, length=n_steps, unroll=2)
-    (a_seq, mdec_seq, markm_seq, markpos_seq, srv_seq, tdone_seq,
-     adm_seq, mr_seq, pos_seq, shed_seq, e_seq) = outs
+    carry, outs = jax.lax.scan(step, carry0, None, length=n_steps)
+    (a_seq, mdec_seq, key_seq, srv_seq, tdone_seq,
+     adm_seq, mr_seq, pos_seq, shed_seq) = outs
     (t, n_adm, rr, ph, neps, nuse, done,
      busy, qlen, n_route, n_srv, nbat, needs,
-     fcur, rty, infl, ndrop, nshed) = carry
+     fcur, rty, infl, ndrop, nshed, energy) = carry
 
     # --- vectorized per-request reconstruction --------------------------
     # Substream positions are per replica: request p on replica m resolves
     # at the serve (or drop) whose interval [base, base + a) contains p.
-    # Scatter each resolver's parity-tagged step (2*step + is_serve) at
-    # (replica, base) and cummax along positions — the single-server trick,
-    # one row per replica (+1 dump row for other steps); the parity of the
-    # governing mark says served vs crash-dropped.  Carried q0 requests
-    # occupy positions [0, c0), this chunk's routed arrivals [c0, n_route).
-    energy = jnp.sum(e_seq)
-    P_sub = Q0 + size  # max substream length per replica
-    steps32 = jnp.arange(n_steps, dtype=jnp.int32)
-    vals = 2 * steps32 + srv_seq.astype(jnp.int32)
-    mark = jnp.full((M + 1, P_sub), -1, dtype=jnp.int32).at[
-        markm_seq, markpos_seq
-    ].max(vals, mode="drop")
-    vcum = jax.lax.cummax(mark[:M], axis=1)
-    epoch_of = vcum >> 1  # the resolving step index
-    # a position is resolved iff it falls inside a mark interval AND below
-    # the replica's resolved count (cummax carries the last mark past the
-    # end of what was actually resolved — e.g. a budget-cut or drain=False
-    # run leaves a queued tail that must stay unresolved)
-    pos_grid = jnp.arange(P_sub)[None, :]
-    resolved = (vcum >= 0) & (pos_grid < (n_srv + ndrop)[:, None])
-    served_grid = resolved & ((vcum & 1) == 1)
-    dropped_grid = resolved & ((vcum & 1) == 0)
-    comp_grid = tdone_seq[jnp.clip(epoch_of, 0)]
+    # Sorting the steps by their (replica, base) key lines each replica's
+    # intervals up in position order, so the resolving step of (m, p) is
+    # the last key <= (m, p): one binary search per request.  (A running
+    # max over a positions grid would do it in O(size), but compiles for
+    # minutes on TPU.)  The resolving step's serve flag says served vs
+    # crash-dropped; positions at or past the replica's resolved count stay
+    # unresolved (a budget-cut or drain=False run leaves a queued tail).
+    # Carried q0 requests occupy positions [0, c0), this chunk's routed
+    # arrivals [c0, n_route).
+    keys, key_step = jax.lax.sort(
+        (key_seq, jnp.arange(n_steps, dtype=jnp.int32)), num_keys=1
+    )
+    n_res = n_srv + ndrop
+
+    def resolve(m, p):
+        """(served, dropped, completion time) of positions p on replicas m."""
+        q = (m * (P_sub + 1) + p).astype(key_t)
+        last = jnp.searchsorted(keys, q, side="right") - 1
+        step = key_step[jnp.clip(last, 0)]
+        done = p < n_res[m]
+        return done & srv_seq[step], done & ~srv_seq[step], tdone_seq[step]
 
     # carried-queue part: positions [0, Q0) of each replica's substream
     q0_fin = jnp.isfinite(q0_times)
-    q0_served = served_grid[:, :Q0] & q0_fin
-    q0_dropped = dropped_grid[:, :Q0] & q0_fin
-    q0_comp = comp_grid[:, :Q0]
+    q0_served, q0_dropped, q0_comp = resolve(
+        midx[:, None], jnp.arange(Q0)[None, :]
+    )
+    q0_served = q0_served & q0_fin
+    q0_dropped = q0_dropped & q0_fin
     q0_lat = jnp.where(q0_served, q0_comp - q0_times, 0.0)
     q0_miss = jnp.sum(q0_served & (q0_comp > q0_dl))
 
@@ -602,10 +604,11 @@ def _fleet_scan_core(
         shed_seq, mode="drop"
     )
     admitted = (arr_server < M) & ~arr_shed
-    ms = jnp.clip(arr_server, 0, M - 1)
-    arr_served = admitted & served_grid[ms, arr_pos]
-    arr_dropped = admitted & dropped_grid[ms, arr_pos]
-    arr_comp = comp_grid[ms, arr_pos]
+    arr_served, arr_dropped, arr_comp = resolve(
+        jnp.clip(arr_server, 0, M - 1), arr_pos
+    )
+    arr_served = admitted & arr_served
+    arr_dropped = admitted & arr_dropped
     arr_lat = jnp.where(arr_served, arr_comp - arrivals, 0.0)
     arr_miss = jnp.sum(arr_served & (arr_comp > deadlines))
 
@@ -893,7 +896,9 @@ def simulate_fleet(
     # one step per admission, epoch, boundary, or advance; each of those
     # is preceded by at most one advance, so 2x is a hard cap
     cap = _bucket(2 * (n_arr + max_eps + n_bnd) + 2 * M + 8)
-    n_steps = min(_bucket(max(256, (3 * n_arr) // 2 + 2 * M + 8)), cap)
+    # every arrival costs a clock advance plus its admission step, so 2n
+    # steps is a floor; decisions and completions add the rest
+    n_steps = min(_bucket(max(256, (5 * n_arr) // 2 + 2 * M + 8)), cap)
     while True:
         out = _fleet_jit(
             jnp.asarray(tables), jnp.asarray(thr), jnp.asarray(arr),
@@ -2265,7 +2270,7 @@ def run_fleet_grid(
             )
     cap = _bucket(2 * (n_arr_max + max_eps) + 2 * M + 8)
     n_steps = min(
-        _bucket(max(256, (3 * n_arr_max) // 2 + 2 * M + 8)), cap
+        _bucket(max(256, (5 * n_arr_max) // 2 + 2 * M + 8)), cap
     )
     while True:
         fn = _fleet_grid_fn(mesh, int(n_steps), mix)
