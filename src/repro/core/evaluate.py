@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import jax.scipy.linalg  # noqa: F401  (lu_factor / lu_solve)
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .smdp import BatchedSMDP, ModulatedBatchedSMDP, TruncatedSMDP
 
@@ -212,17 +213,18 @@ def evaluate_policy_batched(
     """
     if len(policies) != batch.n_specs:
         raise ValueError(f"{len(policies)} policies for {batch.n_specs} specs")
-    acts = np.asarray(policies, dtype=np.int64)
-    for i in range(batch.n_specs):
-        _check_feasible(batch.feasible[i], acts[i])
-    p = batch.policy_transitions_batched(acts)
-    mu, ok = stationary_distribution_batched(p)
-    return [
-        _finish_from_batch(batch, i, acts[i], mu[i])
-        if ok[i]
-        else evaluate_policy_banded(batch, i, acts[i])
-        for i in range(batch.n_specs)
-    ]
+    with TraceAnnotation("repro.evaluate.batched"):
+        acts = np.asarray(policies, dtype=np.int64)
+        for i in range(batch.n_specs):
+            _check_feasible(batch.feasible[i], acts[i])
+        p = batch.policy_transitions_batched(acts)
+        mu, ok = stationary_distribution_batched(p)
+        return [
+            _finish_from_batch(batch, i, acts[i], mu[i])
+            if ok[i]
+            else evaluate_policy_banded(batch, i, acts[i])
+            for i in range(batch.n_specs)
+        ]
 
 
 # ---------------------------------------------------------------------------

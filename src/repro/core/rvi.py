@@ -64,6 +64,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .evaluate import (
     policy_eval_linear,
@@ -1155,22 +1156,49 @@ def relative_value_iteration_batched(
     SolveReport to the result; healthy batches return results identical
     to guard=False.
     """
-    if guard:
-        return _guarded_batched(
-            batch,
-            eps=eps,
-            max_iter=max_iter,
-            eps_rel=eps_rel,
-            h0=h0,
-            mixed_precision=mixed_precision,
-            accel=accel,
-            backup=backup,
-            accel_kw=dict(
-                accel_period=accel_period,
-                accel_memory=accel_memory,
-                accel_safeguard=accel_safeguard,
-            ),
+    with TraceAnnotation("repro.rvi.solve", accel=accel):
+        accel_kw = dict(
+            accel_period=accel_period,
+            accel_memory=accel_memory,
+            accel_safeguard=accel_safeguard,
         )
+        if guard:
+            return _guarded_batched(
+                batch,
+                eps=eps,
+                max_iter=max_iter,
+                eps_rel=eps_rel,
+                h0=h0,
+                mixed_precision=mixed_precision,
+                accel=accel,
+                backup=backup,
+                accel_kw=accel_kw,
+            )
+        return _solve_batched(
+            batch, eps, max_iter, eps_rel, h0, mixed_precision, accel,
+            backup, **accel_kw,
+        )
+
+
+def _solve_batched(
+    batch,
+    eps: float,
+    max_iter: int,
+    eps_rel: float,
+    h0: Optional[np.ndarray],
+    mixed_precision: bool,
+    accel: str,
+    backup: str,
+    accel_period: int,
+    accel_memory: int,
+    accel_safeguard: bool,
+) -> BatchedRVIResult:
+    """relative_value_iteration_batched without the guard ladder.
+
+    Each coarse float32 loop and each float64 finish runs in a host span
+    of its own (``repro.rvi.f32`` / ``repro.rvi.f64``) that closes once
+    the host holds the loop's result.
+    """
     t0 = time.perf_counter()
     pm = batch.pmfs_banded
     arrs = (
@@ -1187,55 +1215,58 @@ def relative_value_iteration_batched(
             # accelerated f32 coarse phase on the narrow band: the floored
             # thresholds (see below) keep it from stalling, the per-spec
             # safeguards absorb any f32 conditioning loss in the polish
-            pm32 = pm[:, :, : trimmed_band(pm, tol=1e-8)]
-            _, _, h32, span32, it_conv32, acc, rej = _run_accel(
-                jnp.asarray(arrs[0], jnp.float32),
-                jnp.asarray(pm32, jnp.float32),
-                jnp.asarray(arrs[2], jnp.float32),
-                jnp.asarray(arrs[3], jnp.float32),
-                s_max,
-                max(eps, 1e-4),
-                max(eps_rel, 1e-5),
-                max_iter,
-                accel,
-                backup,
-                None if h0 is None else jnp.asarray(h0, jnp.float32),
-                accel_period,
-                accel_memory,
-                accel_safeguard,
-            )
+            with TraceAnnotation("repro.rvi.f32"):
+                pm32 = pm[:, :, : trimmed_band(pm, tol=1e-8)]
+                _, _, h32, span32, it_conv32, acc, rej = _run_accel(
+                    jnp.asarray(arrs[0], jnp.float32),
+                    jnp.asarray(pm32, jnp.float32),
+                    jnp.asarray(arrs[2], jnp.float32),
+                    jnp.asarray(arrs[3], jnp.float32),
+                    s_max,
+                    max(eps, 1e-4),
+                    max(eps_rel, 1e-5),
+                    max_iter,
+                    accel,
+                    backup,
+                    None if h0 is None else jnp.asarray(h0, jnp.float32),
+                    accel_period,
+                    accel_memory,
+                    accel_safeguard,
+                )
             h0 = h32.astype(np.float64)
             it_accel = int(it_conv32.max())
             # float64 finish: plain lockstep from the f32 fixed point (a
             # handful of backups), exact gain from the final greedy policy
-            f64 = tuple(jnp.asarray(a, jnp.float64) for a in arrs)
-            policies, g, h, _, span, it_conv = _rvi_loop_batched(
-                *f64, eps, eps_rel, max_iter, s_max, h0=jnp.asarray(h0)
-            )
-            g_exact, h_exact = _exact_gain(*f64[:4], s_max, policies)
-            ok = np.isfinite(np.asarray(g_exact)) & np.isfinite(
-                np.asarray(h_exact)
-            ).all(axis=-1)
-            g = np.where(ok, np.asarray(g_exact), np.asarray(g))
-            h = np.where(ok[:, None], np.asarray(h_exact), np.asarray(h))
-            policies = np.asarray(policies)
-            span = np.asarray(span)
-            it_conv = np.asarray(it_conv) + it_accel
+            with TraceAnnotation("repro.rvi.f64"):
+                f64 = tuple(jnp.asarray(a, jnp.float64) for a in arrs)
+                policies, g, h, _, span, it_conv = _rvi_loop_batched(
+                    *f64, eps, eps_rel, max_iter, s_max, h0=jnp.asarray(h0)
+                )
+                g_exact, h_exact = _exact_gain(*f64[:4], s_max, policies)
+                ok = np.isfinite(np.asarray(g_exact)) & np.isfinite(
+                    np.asarray(h_exact)
+                ).all(axis=-1)
+                g = np.where(ok, np.asarray(g_exact), np.asarray(g))
+                h = np.where(ok[:, None], np.asarray(h_exact), np.asarray(h))
+                policies = np.asarray(policies)
+                span = np.asarray(span)
+                it_conv = np.asarray(it_conv) + it_accel
             acc, rej = np.asarray(acc), np.asarray(rej)
         else:
-            policies, g, h, span, it_conv, acc, rej = _run_accel(
-                *(jnp.asarray(a, jnp.float64) for a in arrs),
-                s_max,
-                eps,
-                eps_rel,
-                max_iter,
-                accel,
-                backup,
-                None if h0 is None else jnp.asarray(h0, jnp.float64),
-                accel_period,
-                accel_memory,
-                accel_safeguard,
-            )
+            with TraceAnnotation("repro.rvi.f64"):
+                policies, g, h, span, it_conv, acc, rej = _run_accel(
+                    *(jnp.asarray(a, jnp.float64) for a in arrs),
+                    s_max,
+                    eps,
+                    eps_rel,
+                    max_iter,
+                    accel,
+                    backup,
+                    None if h0 is None else jnp.asarray(h0, jnp.float64),
+                    accel_period,
+                    accel_memory,
+                    accel_safeguard,
+                )
         return BatchedRVIResult(
             policies=policies,
             g=g,
@@ -1251,38 +1282,43 @@ def relative_value_iteration_batched(
     if mixed_precision:
         # the float32 phase cannot resolve pmf mass below its epsilon anyway,
         # so it runs on a narrower band than the float64 polish
-        pm32 = pm[:, :, : trimmed_band(pm, tol=1e-8)]
-        coarse = _rvi_loop_batched(
-            jnp.asarray(arrs[0], jnp.float32),
-            jnp.asarray(pm32, jnp.float32),
-            jnp.asarray(arrs[2], jnp.float32),
-            jnp.asarray(arrs[3], jnp.float32),
-            max(eps, 1e-4),
-            max(eps_rel, 1e-5),
-            max_iter,
-            s_max,
-            h0=None if h0 is None else jnp.asarray(h0, jnp.float32),
-            backup_kind=backup,
-        )
-        h0 = np.asarray(coarse[2], np.float64)
-        it_coarse = int(coarse[3])
+        with TraceAnnotation("repro.rvi.f32"):
+            pm32 = pm[:, :, : trimmed_band(pm, tol=1e-8)]
+            coarse = _rvi_loop_batched(
+                jnp.asarray(arrs[0], jnp.float32),
+                jnp.asarray(pm32, jnp.float32),
+                jnp.asarray(arrs[2], jnp.float32),
+                jnp.asarray(arrs[3], jnp.float32),
+                max(eps, 1e-4),
+                max(eps_rel, 1e-5),
+                max_iter,
+                s_max,
+                h0=None if h0 is None else jnp.asarray(h0, jnp.float32),
+                backup_kind=backup,
+            )
+            h0 = np.asarray(coarse[2], np.float64)
+            it_coarse = int(coarse[3])
     else:
         it_coarse = 0
-    policies, g, h, it, span, it_conv = _rvi_loop_batched(
-        *(jnp.asarray(a, jnp.float64) for a in arrs),
-        eps,
-        eps_rel,
-        max_iter,
-        s_max,
-        h0=None if h0 is None else jnp.asarray(h0, jnp.float64),
-    )
-    g = np.asarray(g)
-    span = np.asarray(span)
+    with TraceAnnotation("repro.rvi.f64"):
+        policies, g, h, it, span, it_conv = _rvi_loop_batched(
+            *(jnp.asarray(a, jnp.float64) for a in arrs),
+            eps,
+            eps_rel,
+            max_iter,
+            s_max,
+            h0=None if h0 is None else jnp.asarray(h0, jnp.float64),
+        )
+        policies = np.asarray(policies)
+        g = np.asarray(g)
+        h = np.asarray(h)
+        span = np.asarray(span)
+        it_conv = np.asarray(it_conv)
     return BatchedRVIResult(
-        policies=np.asarray(policies),
+        policies=policies,
         g=g,
-        h=np.asarray(h),
-        iterations=np.asarray(it_conv) + it_coarse,
+        h=h,
+        iterations=it_conv + it_coarse,
         span=span,
         converged=span < np.maximum(eps, eps_rel * np.abs(g)),
         wall_time_s=time.perf_counter() - t0,

@@ -46,6 +46,7 @@ import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .service_models import ServiceModel, Profile
 
@@ -480,6 +481,14 @@ def build_smdp_batched(specs: Sequence[SMDPSpec]) -> BatchedSMDP:
                 "batched specs must share (s_max, b_max); got "
                 f"({sp.s_max}, {sp.b_max}) vs ({s_max}, {b_max})"
             )
+    with TraceAnnotation("repro.smdp.build", specs=len(specs), s_max=s_max):
+        return _assemble_batched(specs, s_max, b_max)
+
+
+def _assemble_batched(
+    specs: List[SMDPSpec], s_max: int, b_max: int
+) -> BatchedSMDP:
+    """The arrays of build_smdp_batched for validated specs."""
     N = len(specs)
     S = s_max + 2
     A = b_max + 1

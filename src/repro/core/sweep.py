@@ -49,6 +49,7 @@ import signal
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .evaluate import (
     PolicyEval,
@@ -192,25 +193,26 @@ def _greedy_c_o(batch) -> np.ndarray:
     stationary solve; specs whose greedy chain degenerates keep the paper
     default of 100 (same fallback as the serial resolver).
     """
-    pols = np.stack(
-        [
-            greedy_policy(sp.s_max, sp.b_min, sp.b_max)
-            for sp in batch.specs
-        ]
-    )
-    p = batch.policy_transitions_batched(pols)
-    mu, ok = stationary_distribution_batched(p)
-    out = np.empty(batch.n_specs)
-    for i in range(batch.n_specs):
-        if ok[i]:
-            g = _finish_from_batch(batch, i, pols[i], mu[i]).g
-        else:
-            try:
-                g = evaluate_policy_banded(batch, i, pols[i]).g
-            except RuntimeError:
-                g = 100.0
-        out[i] = max(100.0, 2.0 * g)
-    return out
+    with TraceAnnotation("repro.evaluate.greedy"):
+        pols = np.stack(
+            [
+                greedy_policy(sp.s_max, sp.b_min, sp.b_max)
+                for sp in batch.specs
+            ]
+        )
+        p = batch.policy_transitions_batched(pols)
+        mu, ok = stationary_distribution_batched(p)
+        out = np.empty(batch.n_specs)
+        for i in range(batch.n_specs):
+            if ok[i]:
+                g = _finish_from_batch(batch, i, pols[i], mu[i]).g
+            else:
+                try:
+                    g = evaluate_policy_banded(batch, i, pols[i]).g
+                except RuntimeError:
+                    g = 100.0
+            out[i] = max(100.0, 2.0 * g)
+        return out
 
 
 def resolve_abstract_cost_batched(
@@ -678,192 +680,193 @@ def sweep_solve(
     fingerprint.
     """
     specs = list(specs)
-    flags = {sp.buffer is not None for sp in specs}
-    if len(flags) > 1:
-        raise ValueError(
-            "sweep_solve cannot mix finite-buffer and tail-abstracted "
-            "specs in one batch; solve the two families separately"
+    with TraceAnnotation("repro.sweep.solve", specs=len(specs)):
+        flags = {sp.buffer is not None for sp in specs}
+        if len(flags) > 1:
+            raise ValueError(
+                "sweep_solve cannot mix finite-buffer and tail-abstracted "
+                "specs in one batch; solve the two families separately"
+            )
+        if flags and flags.pop():
+            # finite-buffer solves: no abstract tail to calibrate, and Delta
+            # is not a truncation error (B is physical) — never regrow
+            auto_c_o = False
+            delta = None
+        specs = pad_specs(specs)
+        if not specs:
+            return []
+        if accel == "auto":
+            accel = (
+                "mpi"
+                if max(sp.rho for sp in specs) >= _ACCEL_RHO_THRESHOLD
+                else "none"
+            )
+        # chain the work along rho (then w2) once, up front: the warm-start
+        # anchors become the extreme-rho specs, where mixing is worst, and the
+        # c_o probe batch can be reused (row-patched) as the first solve batch
+        order = sorted(
+            range(len(specs)), key=lambda i: (specs[i].rho, specs[i].w2)
         )
-    if flags and flags.pop():
-        # finite-buffer solves: no abstract tail to calibrate, and Delta
-        # is not a truncation error (B is physical) — never regrow
-        auto_c_o = False
-        delta = None
-    specs = pad_specs(specs)
-    if not specs:
-        return []
-    if accel == "auto":
-        accel = (
-            "mpi"
-            if max(sp.rho for sp in specs) >= _ACCEL_RHO_THRESHOLD
-            else "none"
-        )
-    # chain the work along rho (then w2) once, up front: the warm-start
-    # anchors become the extreme-rho specs, where mixing is worst, and the
-    # c_o probe batch can be reused (row-patched) as the first solve batch
-    order = sorted(
-        range(len(specs)), key=lambda i: (specs[i].rho, specs[i].w2)
-    )
-    ckpt = state = None
-    if checkpoint_dir is not None:
-        if chunk_size is None:
-            chunk_size = _DEFAULT_CHUNK
-        ckpt = _SweepCheckpointer(
-            checkpoint_dir,
-            _fingerprint(
-                specs,
-                dict(
-                    kind="sweep_solve",
-                    eps=eps,
-                    max_iter=max_iter,
-                    delta=delta,
-                    grow_factor=grow_factor,
-                    max_s_max=max_s_max,
-                    auto_c_o=auto_c_o,
-                    accel=accel,
-                    backup=backup,
-                    guard=guard,
-                    chunk_size=chunk_size,
+        ckpt = state = None
+        if checkpoint_dir is not None:
+            if chunk_size is None:
+                chunk_size = _DEFAULT_CHUNK
+            ckpt = _SweepCheckpointer(
+                checkpoint_dir,
+                _fingerprint(
+                    specs,
+                    dict(
+                        kind="sweep_solve",
+                        eps=eps,
+                        max_iter=max_iter,
+                        delta=delta,
+                        grow_factor=grow_factor,
+                        max_s_max=max_s_max,
+                        auto_c_o=auto_c_o,
+                        accel=accel,
+                        backup=backup,
+                        guard=guard,
+                        chunk_size=chunk_size,
+                    ),
                 ),
-            ),
-            keep_last_k,
-        )
-        state = ckpt.load()
-    prebuilt = c_os = None
-    if auto_c_o:
-        if state is not None:
-            c_os = state["meta//c_o"]
-            base = [
-                dataclasses.replace(specs[i], c_o=float(c))
-                for i, c in zip(order, c_os)
-            ]
+                keep_last_k,
+            )
+            state = ckpt.load()
+        prebuilt = c_os = None
+        if auto_c_o:
+            if state is not None:
+                c_os = state["meta//c_o"]
+                base = [
+                    dataclasses.replace(specs[i], c_o=float(c))
+                    for i, c in zip(order, c_os)
+                ]
+            else:
+                probe_batch = build_smdp_batched(
+                    [dataclasses.replace(specs[i], c_o=0.0) for i in order]
+                )
+                c_os = _greedy_c_o(probe_batch)
+                patched = probe_batch.with_c_o(c_os)
+                base = list(patched.specs)
+                if ckpt is None:
+                    # resumable runs always rebuild chunk batches from specs,
+                    # so a resumed first round matches the one-shot bit-for-bit
+                    prebuilt = patched
         else:
-            probe_batch = build_smdp_batched(
-                [dataclasses.replace(specs[i], c_o=0.0) for i in order]
+            base = [specs[i] for i in order]
+        pending = list(zip(order, base))
+        results: List[SolveResult] = [None] * len(specs)  # type: ignore[list-item]
+        report_parts: List[Tuple[SolveReport, List[int]]] = []
+        next_round: List[tuple] = []
+        if state is not None:
+            base_by_idx = dict(pending)
+            done_idxs = sorted(
+                {int(k.split("//")[1]) for k in state if k.startswith("done//")}
             )
-            c_os = _greedy_c_o(probe_batch)
-            patched = probe_batch.with_c_o(c_os)
-            base = list(patched.specs)
-            if ckpt is None:
-                # resumable runs always rebuild chunk batches from specs,
-                # so a resumed first round matches the one-shot bit-for-bit
-                prebuilt = patched
-    else:
-        base = [specs[i] for i in order]
-    pending = list(zip(order, base))
-    results: List[SolveResult] = [None] * len(specs)  # type: ignore[list-item]
-    report_parts: List[Tuple[SolveReport, List[int]]] = []
-    next_round: List[tuple] = []
-    if state is not None:
-        base_by_idx = dict(pending)
-        done_idxs = sorted(
-            {int(k.split("//")[1]) for k in state if k.startswith("done//")}
-        )
-        for idx in done_idxs:
-            sp, rvi, ev = _unpack_result(state, idx, base_by_idx[idx])
-            results[idx] = SolveResult(spec=sp, rvi=rvi, eval=ev)
-        if guard and done_idxs:
-            report_parts.append(_restored_report(results, done_idxs, eps))
-        pending = [
-            (int(i), dataclasses.replace(base_by_idx[int(i)], s_max=int(s)))
-            for i, s in zip(
-                state["meta//pending_idx"], state["meta//pending_smax"]
-            )
-        ]
-        next_round = [
-            (int(i), dataclasses.replace(base_by_idx[int(i)], s_max=int(s)))
-            for i, s in zip(state["meta//next_idx"], state["meta//next_smax"])
-        ]
-    rvi_kw = dict(accel=accel, backup=backup)
-    preempt = _PreemptGuard(ckpt is not None)
-    try:
-        while pending or next_round:
-            if not pending:
-                pending, next_round = next_round, []
-            plan = _round_plan(pending, chunk_size)
-            for ci, chunk in enumerate(plan):
-                if (
-                    prebuilt is not None
-                    and len(chunk) == prebuilt.n_specs
-                    and all(
-                        a is b for (_, a), b in zip(chunk, prebuilt.specs)
-                    )
-                ):
-                    batch = prebuilt
-                else:
-                    batch = build_smdp_batched([sp for _, sp in chunk])
-                rvi = relative_value_iteration_batched(
-                    batch,
-                    eps=eps,
-                    max_iter=max_iter,
-                    h0=_anchor_warm_start(batch, eps, max_iter, **rvi_kw),
-                    guard=guard,
-                    **rvi_kw,
+            for idx in done_idxs:
+                sp, rvi, ev = _unpack_result(state, idx, base_by_idx[idx])
+                results[idx] = SolveResult(spec=sp, rvi=rvi, eval=ev)
+            if guard and done_idxs:
+                report_parts.append(_restored_report(results, done_idxs, eps))
+            pending = [
+                (int(i), dataclasses.replace(base_by_idx[int(i)], s_max=int(s)))
+                for i, s in zip(
+                    state["meta//pending_idx"], state["meta//pending_smax"]
                 )
-                if rvi.report is not None:
-                    healthy = rvi.report.healthy
-                    report_parts.append(
-                        (rvi.report, [idx for idx, _ in chunk])
-                    )
-                else:
-                    healthy = np.ones(len(chunk), dtype=bool)
-                evs = _eval_healthy(
-                    batch,
-                    rvi.policies,
-                    healthy,
-                    evaluate_policy_batched,
-                    lambda sp: sp.s_max + 1,
-                )
-                for row, (idx, sp) in enumerate(chunk):
-                    ev = evs[row]
-                    if not healthy[row]:
-                        # ladder-exhausted row: keep the NaN-flagged result
-                        # (growing the truncation cannot heal divergence)
-                        results[idx] = SolveResult(
-                            spec=sp, rvi=rvi.unstack(row), eval=ev
+            ]
+            next_round = [
+                (int(i), dataclasses.replace(base_by_idx[int(i)], s_max=int(s)))
+                for i, s in zip(state["meta//next_idx"], state["meta//next_smax"])
+            ]
+        rvi_kw = dict(accel=accel, backup=backup)
+        preempt = _PreemptGuard(ckpt is not None)
+        try:
+            while pending or next_round:
+                if not pending:
+                    pending, next_round = next_round, []
+                plan = _round_plan(pending, chunk_size)
+                for ci, chunk in enumerate(plan):
+                    if (
+                        prebuilt is not None
+                        and len(chunk) == prebuilt.n_specs
+                        and all(
+                            a is b for (_, a), b in zip(chunk, prebuilt.specs)
                         )
-                    elif (
-                        delta is None
-                        or ev.delta < delta
-                        or sp.s_max >= max_s_max
                     ):
-                        results[idx] = SolveResult(
-                            spec=sp, rvi=rvi.unstack(row), eval=ev
+                        batch = prebuilt
+                    else:
+                        batch = build_smdp_batched([sp for _, sp in chunk])
+                    rvi = relative_value_iteration_batched(
+                        batch,
+                        eps=eps,
+                        max_iter=max_iter,
+                        h0=_anchor_warm_start(batch, eps, max_iter, **rvi_kw),
+                        guard=guard,
+                        **rvi_kw,
+                    )
+                    if rvi.report is not None:
+                        healthy = rvi.report.healthy
+                        report_parts.append(
+                            (rvi.report, [idx for idx, _ in chunk])
                         )
                     else:
-                        next_round.append(
-                            (
-                                idx,
-                                dataclasses.replace(
-                                    sp,
-                                    s_max=min(
-                                        int(np.ceil(sp.s_max * grow_factor)),
-                                        max_s_max,
-                                    ),
-                                ),
-                            )
-                        )
-                if ckpt is not None:
-                    remaining = [it for ch in plan[ci + 1 :] for it in ch]
-                    ckpt.save(
-                        _sweep_state(results, remaining, next_round, c_os)
+                        healthy = np.ones(len(chunk), dtype=bool)
+                    evs = _eval_healthy(
+                        batch,
+                        rvi.policies,
+                        healthy,
+                        evaluate_policy_batched,
+                        lambda sp: sp.s_max + 1,
                     )
-                    if preempt.hit and (remaining or next_round):
-                        ckpt.wait()  # the named step must be durable
-                        raise SweepPreempted(checkpoint_dir, ckpt.step - 1)
-            prebuilt = None
-            pending, next_round = next_round, []
-    finally:
-        preempt.restore()
-        if ckpt is not None:
-            ckpt.wait()
-    if report_sink is not None:
-        report_sink.append(
-            SolveReport.merged(report_parts, len(specs), eps)
-            if report_parts
-            else _restored_report(results, list(range(len(specs))), eps)[0]
-        )
-    return results
+                    for row, (idx, sp) in enumerate(chunk):
+                        ev = evs[row]
+                        if not healthy[row]:
+                            # ladder-exhausted row: keep the NaN-flagged result
+                            # (growing the truncation cannot heal divergence)
+                            results[idx] = SolveResult(
+                                spec=sp, rvi=rvi.unstack(row), eval=ev
+                            )
+                        elif (
+                            delta is None
+                            or ev.delta < delta
+                            or sp.s_max >= max_s_max
+                        ):
+                            results[idx] = SolveResult(
+                                spec=sp, rvi=rvi.unstack(row), eval=ev
+                            )
+                        else:
+                            next_round.append(
+                                (
+                                    idx,
+                                    dataclasses.replace(
+                                        sp,
+                                        s_max=min(
+                                            int(np.ceil(sp.s_max * grow_factor)),
+                                            max_s_max,
+                                        ),
+                                    ),
+                                )
+                            )
+                    if ckpt is not None:
+                        remaining = [it for ch in plan[ci + 1 :] for it in ch]
+                        ckpt.save(
+                            _sweep_state(results, remaining, next_round, c_os)
+                        )
+                        if preempt.hit and (remaining or next_round):
+                            ckpt.wait()  # the named step must be durable
+                            raise SweepPreempted(checkpoint_dir, ckpt.step - 1)
+                prebuilt = None
+                pending, next_round = next_round, []
+        finally:
+            preempt.restore()
+            if ckpt is not None:
+                ckpt.wait()
+        if report_sink is not None:
+            report_sink.append(
+                SolveReport.merged(report_parts, len(specs), eps)
+                if report_parts
+                else _restored_report(results, list(range(len(specs))), eps)[0]
+            )
+        return results
 
 
 # ---------------------------------------------------------------------------

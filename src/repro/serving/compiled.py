@@ -56,6 +56,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.service_models import ServiceModel  # noqa: F401  (x64 on import)
 
@@ -1020,86 +1021,102 @@ def run_grid(
     (mean latency, power, weighted cost, sketch quantiles) without ever
     materializing per-request data.
     """
-    tables = np.asarray(tables, dtype=np.int64)
-    arr = np.asarray(arrivals, dtype=np.float64)
-    if tables.ndim == 2:
-        tables = tables[:, None, :]
-    elif tables.ndim != 3:
-        raise ValueError(
-            f"tables must be (P, L) or (P, K, L); got {tables.shape}"
+    with TraceAnnotation("repro.grid.prepare", lanes=len(arrivals)):
+        tables = np.asarray(tables, dtype=np.int64)
+        arr = np.asarray(arrivals, dtype=np.float64)
+        if tables.ndim == 2:
+            tables = tables[:, None, :]
+        elif tables.ndim != 3:
+            raise ValueError(
+                f"tables must be (P, L) or (P, K, L); got {tables.shape}"
+            )
+        if arr.ndim != 2:
+            raise ValueError("run_grid wants (S, N) arrivals")
+        if arr.shape[1] < _ADMIT_W or not np.isinf(arr[:, -_ADMIT_W:]).all():
+            raise ValueError("pad each trace with pad_arrivals first")
+        bel = _check_phase_mode(phase_mode, beliefs, tables.shape[1])
+        if bel is not None:
+            if phases is not None:
+                raise ValueError("phases= and beliefs= are mutually exclusive")
+            if bel.ndim != 3 or bel.shape[:2] != arr.shape:
+                raise ValueError(
+                    f"beliefs must be (S, N, K) aligned with arrivals "
+                    f"{arr.shape}; got {bel.shape}"
+                )
+            if phase_mode == "belief_argmax":
+                phases = np.argmax(bel, axis=-1)
+                bel = None
+        elif tables.shape[1] > 1 and phases is None:
+            raise ValueError("phase-indexed tables need phases= (S, N) ints")
+        mix = phase_mode == "belief_mix"
+        dl = (
+            np.asarray(deadlines, dtype=np.float64)
+            if deadlines is not None
+            else np.full_like(arr, np.inf)
         )
-    if arr.ndim != 2:
-        raise ValueError("run_grid wants (S, N) arrivals")
-    if arr.shape[1] < _ADMIT_W or not np.isinf(arr[:, -_ADMIT_W:]).all():
-        raise ValueError("pad each trace with pad_arrivals first")
-    bel = _check_phase_mode(phase_mode, beliefs, tables.shape[1])
-    if bel is not None:
         if phases is not None:
-            raise ValueError("phases= and beliefs= are mutually exclusive")
-        if bel.ndim != 3 or bel.shape[:2] != arr.shape:
-            raise ValueError(
-                f"beliefs must be (S, N, K) aligned with arrivals "
-                f"{arr.shape}; got {bel.shape}"
-            )
-        if phase_mode == "belief_argmax":
-            phases = np.argmax(bel, axis=-1)
-            bel = None
-    elif tables.shape[1] > 1 and phases is None:
-        raise ValueError("phase-indexed tables need phases= (S, N) ints")
-    mix = phase_mode == "belief_mix"
-    dl = (
-        np.asarray(deadlines, dtype=np.float64)
-        if deadlines is not None
-        else np.full_like(arr, np.inf)
-    )
-    if phases is not None:
-        ph = np.asarray(phases, dtype=np.int64)
-        if ph.shape != arr.shape:
-            raise ValueError(f"phases shape {ph.shape} != arrivals {arr.shape}")
-        if ph.min() < 0 or ph.max() >= tables.shape[1]:
-            raise ValueError(
-                f"phases outside the table stack [0, {tables.shape[1]})"
-            )
-    else:
-        ph = np.zeros(arr.shape, dtype=np.int64)
-    means = np.asarray(means, dtype=np.float64)
-    zeta_a = (
-        np.zeros(b_max + 1)
-        if zeta is None
-        else np.asarray(zeta, dtype=np.float64).copy()
-    )
-    zeta_a[0] = 0.0  # a = 0 never accounts energy (the kernel adds zeta[a])
-    if draws is None:
-        draws = np.ones((arr.shape[0], 1))
-    draws = np.asarray(draws, dtype=np.float64)
-    n_arr_max = int(np.isfinite(arr).sum(axis=1).max())
-    max_eps = 2 * n_arr_max + 2 if max_epochs is None else int(max_epochs)
-    edges = (
-        default_hist_edges(means)
-        if hist_edges is None
-        else np.asarray(hist_edges, dtype=np.float64)
-    )
-    cap = _bucket(n_arr_max + max_eps + 1)
-    ck = ("grid", arr.shape, tables.shape, cap, mix)
-    n_steps = _initial_steps(ck, n_arr_max, max_eps, cap)
-    bel_j = (
-        jnp.zeros((arr.shape[0], 1, 1)) if bel is None else jnp.asarray(bel)
-    )  # unused unless mix
-    while True:
-        out = _grid_jit(
+            ph = np.asarray(phases, dtype=np.int64)
+            if ph.shape != arr.shape:
+                raise ValueError(
+                    f"phases shape {ph.shape} != arrivals {arr.shape}"
+                )
+            if ph.min() < 0 or ph.max() >= tables.shape[1]:
+                raise ValueError(
+                    f"phases outside the table stack [0, {tables.shape[1]})"
+                )
+        else:
+            ph = np.zeros(arr.shape, dtype=np.int64)
+        means = np.asarray(means, dtype=np.float64)
+        zeta_a = (
+            np.zeros(b_max + 1)
+            if zeta is None
+            else np.asarray(zeta, dtype=np.float64).copy()
+        )
+        # a = 0 never accounts energy (the kernel adds zeta[a])
+        zeta_a[0] = 0.0
+        if draws is None:
+            draws = np.ones((arr.shape[0], 1))
+        draws = np.asarray(draws, dtype=np.float64)
+        n_arr_max = int(np.isfinite(arr).sum(axis=1).max())
+        max_eps = 2 * n_arr_max + 2 if max_epochs is None else int(max_epochs)
+        edges = (
+            default_hist_edges(means)
+            if hist_edges is None
+            else np.asarray(hist_edges, dtype=np.float64)
+        )
+        cap = _bucket(n_arr_max + max_eps + 1)
+        ck = ("grid", arr.shape, tables.shape, cap, mix)
+        n_steps = _initial_steps(ck, n_arr_max, max_eps, cap)
+        bel_j = (  # unused unless mix
+            jnp.zeros((arr.shape[0], 1, 1))
+            if bel is None
+            else jnp.asarray(bel)
+        )
+        # uploaded once: an escalated dispatch reuses the device arrays
+        dev = (
             jnp.asarray(tables), jnp.asarray(arr), jnp.asarray(dl),
             jnp.asarray(ph), bel_j, jnp.asarray(draws), jnp.asarray(means),
             jnp.asarray(zeta_a), jnp.asarray(edges),
-            float(t0), np.inf if horizon is None else float(horizon),
-            max_eps, bool(drain), int(b_max), int(n_steps), mix,
         )
-        if n_steps >= cap or not bool(np.asarray(out["incomplete"]).any()):
+    while True:
+        with TraceAnnotation("repro.grid.run", steps_run=n_steps):
+            out = _grid_jit(
+                *dev,
+                float(t0), np.inf if horizon is None else float(horizon),
+                max_eps, bool(drain), int(b_max), int(n_steps), mix,
+            )
+            done = n_steps >= cap or not bool(
+                np.asarray(out["incomplete"]).any()
+            )
+        if done:
             break
         n_steps = min(2 * n_steps, cap)
-    _NSTEPS_CACHE[ck] = min(
-        _bucket(int(np.asarray(out["n_steps_used"]).max()) + 1), cap
-    )
-    return _grid_post(out, edges, t0, zeta is not None)
+    with TraceAnnotation("repro.grid.post", steps_run=n_steps) as post:
+        # the vmapped scan runs every lane and table to the longest
+        used = int(np.asarray(out["n_steps_used"]).max())
+        post.set_metadata(steps_used=used)
+        _NSTEPS_CACHE[ck] = min(_bucket(used + 1), cap)
+        return _grid_post(out, edges, t0, zeta is not None)
 
 
 def _grid_post(out, edges, t0, have_energy):

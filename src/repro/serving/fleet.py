@@ -72,6 +72,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.service_models import ServiceModel  # noqa: F401  (x64 on import)
 
@@ -2180,136 +2181,157 @@ def run_fleet_grid(
     make_sim_mesh()` builds the 1-D all-devices mesh); S is padded to a
     device multiple by repeating the first lane and trimmed on return.
     """
-    tables = np.asarray(tables, dtype=np.int64)
-    if tables.ndim == 2:
-        if n_replicas is None:
+    with TraceAnnotation("repro.fleet.prepare", lanes=len(arrivals)):
+        tables = np.asarray(tables, dtype=np.int64)
+        if tables.ndim == 2:
+            if n_replicas is None:
+                raise ValueError(
+                    "(P, L) tables need n_replicas=M (or pass (P, M, L))"
+                )
+            tables = np.repeat(tables[:, None, :], n_replicas, axis=1)
+        if tables.ndim == 3:
+            tables = tables[:, :, None, :]
+        if tables.ndim != 4:
             raise ValueError(
-                "(P, L) tables need n_replicas=M (or pass (P, M, L))"
+                f"tables must be (P, L), (P, M, L) or (P, M, K, L); "
+                f"got {tables.shape}"
             )
-        tables = np.repeat(tables[:, None, :], n_replicas, axis=1)
-    if tables.ndim == 3:
-        tables = tables[:, :, None, :]
-    if tables.ndim != 4:
-        raise ValueError(
-            f"tables must be (P, L), (P, M, L) or (P, M, K, L); "
-            f"got {tables.shape}"
-        )
-    if n_replicas is not None and tables.shape[1] != n_replicas:
-        raise ValueError(
-            f"tables have {tables.shape[1]} replicas, n_replicas={n_replicas}"
-        )
-    Pn, M, K, L = tables.shape
-    arr = np.asarray(arrivals, dtype=np.float64)
-    if arr.ndim != 2:
-        raise ValueError("run_fleet_grid wants (S, N) arrivals")
-    bel = None
-    if phase_mode != "oracle" or beliefs is not None:
-        if beliefs is not None and np.asarray(beliefs).shape[:2] != arr.shape:
+        if n_replicas is not None and tables.shape[1] != n_replicas:
             raise ValueError(
-                "beliefs must be (S, N, K) aligned with arrivals"
+                f"tables have {tables.shape[1]} replicas, "
+                f"n_replicas={n_replicas}"
             )
-        phases, bel = _belief_phases(phase_mode, beliefs, phases, K)
-    if arr.shape[1] < _ADMIT_W or not np.isinf(arr[:, -_ADMIT_W:]).all():
-        raise ValueError("pad each trace with pad_arrivals first")
-    S, N = arr.shape
-    dl = (
-        np.asarray(deadlines, dtype=np.float64)
-        if deadlines is not None
-        else np.full_like(arr, np.inf)
-    )
-    if phases is not None:
-        ph = np.asarray(phases, dtype=np.int64)
-        if ph.shape != arr.shape:
-            raise ValueError(f"phases shape {ph.shape} != arrivals {arr.shape}")
-        if ph.min() < 0 or ph.max() >= K:
-            raise ValueError(f"phases outside the table stack [0, {K})")
-    else:
-        if K > 1:
-            raise ValueError("phase-indexed tables need phases= (S, N) ints")
-        ph = np.zeros(arr.shape, dtype=np.int64)
-    rids = np.asarray([router_id(r) for r in routers], dtype=np.int64)
-    ru = np.random.default_rng(router_seed).random((S, N, 2))
-    means = np.asarray(means, dtype=np.float64)
-    zeta_a = (
-        np.zeros(b_max + 1)
-        if zeta is None
-        else np.asarray(zeta, dtype=np.float64).copy()
-    )
-    zeta_a[0] = 0.0
-    if draws is None:
-        draws = np.ones((S, 1))
-    draws = np.asarray(draws, dtype=np.float64)
-    if draws.ndim == 1:  # one shared draw stream -> every lane
-        draws = np.tile(draws[None, :], (S, 1))
-    if draws.shape[0] != S:
-        raise ValueError(f"draws lane axis {draws.shape[0]} != S={S}")
-    edges = (
-        default_hist_edges(means)
-        if hist_edges is None
-        else np.asarray(hist_edges, dtype=np.float64)
-    )
-    thrs = np.stack([threshold_gaps(tables[p]) for p in range(Pn)])
-    mix = bel is not None
-    bel_g = (
-        np.asarray(bel, dtype=np.float64) if mix else np.zeros((S, 1, 1))
-    )
-    n_arr_max = int(np.isfinite(arr).sum(axis=1).max())
-    max_eps = (
-        2 * n_arr_max + M + 4 if max_epochs is None else int(max_epochs)
-    )
-    # mesh: pad the lane axis to a device multiple (repeat lane 0), trim
-    pad_s = 0
-    if mesh is not None:
-        ndev = int(np.prod([mesh.shape[a] for a in mesh.axis_names[:1]]))
-        pad_s = (-S) % ndev
-        if pad_s:
-            def _pad(x):
-                return np.concatenate([x, np.repeat(x[:1], pad_s, axis=0)])
-            arr, dl, ph, bel_g, ru, draws = map(
-                _pad, (arr, dl, ph, bel_g, ru, draws)
-            )
-    cap = _bucket(2 * (n_arr_max + max_eps) + 2 * M + 8)
-    n_steps = min(
-        _bucket(max(256, (5 * n_arr_max) // 2 + 2 * M + 8)), cap
-    )
+        Pn, M, K, L = tables.shape
+        arr = np.asarray(arrivals, dtype=np.float64)
+        if arr.ndim != 2:
+            raise ValueError("run_fleet_grid wants (S, N) arrivals")
+        bel = None
+        if phase_mode != "oracle" or beliefs is not None:
+            if (
+                beliefs is not None
+                and np.asarray(beliefs).shape[:2] != arr.shape
+            ):
+                raise ValueError(
+                    "beliefs must be (S, N, K) aligned with arrivals"
+                )
+            phases, bel = _belief_phases(phase_mode, beliefs, phases, K)
+        if arr.shape[1] < _ADMIT_W or not np.isinf(arr[:, -_ADMIT_W:]).all():
+            raise ValueError("pad each trace with pad_arrivals first")
+        S, N = arr.shape
+        dl = (
+            np.asarray(deadlines, dtype=np.float64)
+            if deadlines is not None
+            else np.full_like(arr, np.inf)
+        )
+        if phases is not None:
+            ph = np.asarray(phases, dtype=np.int64)
+            if ph.shape != arr.shape:
+                raise ValueError(
+                    f"phases shape {ph.shape} != arrivals {arr.shape}"
+                )
+            if ph.min() < 0 or ph.max() >= K:
+                raise ValueError(f"phases outside the table stack [0, {K})")
+        else:
+            if K > 1:
+                raise ValueError(
+                    "phase-indexed tables need phases= (S, N) ints"
+                )
+            ph = np.zeros(arr.shape, dtype=np.int64)
+        rids = np.asarray([router_id(r) for r in routers], dtype=np.int64)
+        ru = np.random.default_rng(router_seed).random((S, N, 2))
+        means = np.asarray(means, dtype=np.float64)
+        zeta_a = (
+            np.zeros(b_max + 1)
+            if zeta is None
+            else np.asarray(zeta, dtype=np.float64).copy()
+        )
+        zeta_a[0] = 0.0
+        if draws is None:
+            draws = np.ones((S, 1))
+        draws = np.asarray(draws, dtype=np.float64)
+        if draws.ndim == 1:  # one shared draw stream -> every lane
+            draws = np.tile(draws[None, :], (S, 1))
+        if draws.shape[0] != S:
+            raise ValueError(f"draws lane axis {draws.shape[0]} != S={S}")
+        edges = (
+            default_hist_edges(means)
+            if hist_edges is None
+            else np.asarray(hist_edges, dtype=np.float64)
+        )
+        thrs = np.stack([threshold_gaps(tables[p]) for p in range(Pn)])
+        mix = bel is not None
+        bel_g = (
+            np.asarray(bel, dtype=np.float64) if mix else np.zeros((S, 1, 1))
+        )
+        n_arr_max = int(np.isfinite(arr).sum(axis=1).max())
+        max_eps = (
+            2 * n_arr_max + M + 4 if max_epochs is None else int(max_epochs)
+        )
+        # mesh: pad the lane axis to a device multiple (repeat lane 0), trim
+        pad_s = 0
+        if mesh is not None:
+            ndev = int(np.prod([mesh.shape[a] for a in mesh.axis_names[:1]]))
+            pad_s = (-S) % ndev
+            if pad_s:
+                def _pad(x):
+                    return np.concatenate([x, np.repeat(x[:1], pad_s, axis=0)])
+                arr, dl, ph, bel_g, ru, draws = map(
+                    _pad, (arr, dl, ph, bel_g, ru, draws)
+                )
+        cap = _bucket(2 * (n_arr_max + max_eps) + 2 * M + 8)
+        n_steps = min(
+            _bucket(max(256, (5 * n_arr_max) // 2 + 2 * M + 8)), cap
+        )
+        # uploaded once: an escalated dispatch reuses the device arrays
+        dev = tuple(
+            jnp.asarray(x)
+            for x in (tables, thrs, rids, arr, dl, ph, bel_g, ru, draws,
+                      means, zeta_a, edges)
+        )
     while True:
-        fn = _fleet_grid_fn(mesh, int(n_steps), mix)
-        out = fn(
-            jnp.asarray(tables), jnp.asarray(thrs), jnp.asarray(rids),
-            jnp.asarray(arr), jnp.asarray(dl), jnp.asarray(ph),
-            jnp.asarray(bel_g), jnp.asarray(ru), jnp.asarray(draws),
-            jnp.asarray(means), jnp.asarray(zeta_a), jnp.asarray(edges),
-            float(t0), np.inf if horizon is None else float(horizon),
-            max_eps, bool(drain), int(b_max),
-        )
-        if n_steps >= cap or not bool(np.asarray(out["incomplete"]).any()):
+        with TraceAnnotation("repro.fleet.run", steps_run=n_steps):
+            fn = _fleet_grid_fn(mesh, int(n_steps), mix)
+            out = fn(
+                *dev,
+                float(t0), np.inf if horizon is None else float(horizon),
+                max_eps, bool(drain), int(b_max),
+            )
+            done = n_steps >= cap or not bool(
+                np.asarray(out["incomplete"]).any()
+            )
+        if done:
             break
         n_steps = min(2 * n_steps, cap)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    if pad_s:
-        out = {k: v[:S] for k, v in out.items()}
-    out["hist_edges"] = edges
-    with np.errstate(invalid="ignore", divide="ignore"):
-        span = out["t_final"] - t0
-        # a starved lane (no served request) has no mean latency: NaN,
-        # not 0 — the metrics-satellite convention
-        out["w_mean"] = np.where(
-            out["n_served"] > 0,
-            out["lat_sum"] / np.maximum(out["n_served"], 1),
-            np.nan,
-        )
-        have_energy = zeta is not None
-        out["power"] = np.where(
-            have_energy & (out["n_batches"] > 0) & (span > 0),
-            out["energy"] / span,
-            np.nan,
-        )
-        # time-averaged total backlog (Little): integral of queue+in-
-        # service size over time / span == sum of latencies / span
-        out["q_time_avg"] = np.where(
-            span > 0, out["lat_sum"] / np.where(span > 0, span, 1.0), np.nan
-        )
-        out["events_total"] = int(
-            out["n_served"].sum() + out["n_epochs"].sum()
-        )
-    return out
+    with TraceAnnotation("repro.fleet.post", steps_run=n_steps) as post:
+        out = {k: np.asarray(v) for k, v in out.items()}
+        # the vmapped scan runs every lane, policy and router to the longest
+        post.set_metadata(steps_used=int(out["n_steps_used"].max()))
+        if pad_s:
+            out = {k: v[:S] for k, v in out.items()}
+        out["hist_edges"] = edges
+        with np.errstate(invalid="ignore", divide="ignore"):
+            span = out["t_final"] - t0
+            # a starved lane (no served request) has no mean latency: NaN,
+            # not 0 — the metrics-satellite convention
+            out["w_mean"] = np.where(
+                out["n_served"] > 0,
+                out["lat_sum"] / np.maximum(out["n_served"], 1),
+                np.nan,
+            )
+            have_energy = zeta is not None
+            out["power"] = np.where(
+                have_energy & (out["n_batches"] > 0) & (span > 0),
+                out["energy"] / span,
+                np.nan,
+            )
+            # time-averaged total backlog (Little): integral of queue+in-
+            # service size over time / span == sum of latencies / span
+            out["q_time_avg"] = np.where(
+                span > 0,
+                out["lat_sum"] / np.where(span > 0, span, 1.0),
+                np.nan,
+            )
+            out["events_total"] = int(
+                out["n_served"].sum() + out["n_epochs"].sum()
+            )
+        return out
