@@ -65,6 +65,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from functools import partial
 from typing import Dict, List, Optional, Sequence
 
@@ -238,15 +239,31 @@ class FleetResult:
 # ---------------------------------------------------------------------------
 
 
-def _fleet_scan_core(
+def _fleet_kernel(
     tables, thr_gap, arrivals, deadlines, phases, beliefs, bel0, router_u,
     q0_times, q0_dl, draws, means, zeta, edges, fb, fmult,
     rid, t0, horizon, max_eps, drain, b_max, buf_cap, max_retries,
     rr0, ph0, busy0, nbat0, needs0, fcur0, rty0, infl0, more_coming, t_last,
-    *, n_steps: int, record: bool, mix: bool,
+    *, mix: bool,
 ):
-    """The fleet event kernel: one scan step == one admission, one decision
+    """The fleet event kernel: one step == one admission, one decision
     epoch on one replica, one fault boundary, or one clock advance.
+
+    Returns its three pieces ``(carry0, step, finish)``: the carry before
+    the first step, ``step(carry, _) -> (carry, out)`` (a `lax.scan`
+    body), and ``finish(carry, outs, *, record)``, the per-request
+    reconstruction and aggregates from the final carry and the stacked
+    per-step outputs.  `_fleet_jit` scans a fixed number of steps;
+    `_fleet_grid_core` stops once no instance of its grid is active.
+
+    An inactive step (``done``, or the epoch budget spent) changes no
+    carry: every update but the drain wake is gated on ``active``, and the
+    wake finds nothing new, since an instance turns inactive only after a
+    decision step, whose own wake saw the same stream state and whose
+    decision leaves its replica busy, crashed or empty.  (An instance
+    inactive from the start may wake replicas of a carried queue once; the
+    grid carries none.)  Its outputs are fixed: no dispatch, no admission,
+    and a sort key past every (replica, position) that ``finish`` reads.
 
     Pure jax function (callers jit/vmap).  ``tables`` is (M, K, L);
     ``thr_gap`` the matching threshold_gaps array; ``arrivals`` sorted with
@@ -551,105 +568,110 @@ def _fleet_scan_core(
         fcur0, jnp.asarray(rty0, dtype=i64), infl0, zv, zv,
         jnp.asarray(0.0, dtype=jnp.float64),
     )
-    carry, outs = jax.lax.scan(step, carry0, None, length=n_steps)
-    (a_seq, mdec_seq, key_seq, srv_seq, tdone_seq,
-     adm_seq, mr_seq, pos_seq, shed_seq) = outs
-    (t, n_adm, rr, ph, neps, nuse, done,
-     busy, qlen, n_route, n_srv, nbat, needs,
-     fcur, rty, infl, ndrop, nshed, energy) = carry
 
-    # --- vectorized per-request reconstruction --------------------------
-    # Substream positions are per replica: request p on replica m resolves
-    # at the serve (or drop) whose interval [base, base + a) contains p.
-    # Sorting the steps by their (replica, base) key lines each replica's
-    # intervals up in position order, so the resolving step of (m, p) is
-    # the last key <= (m, p): one binary search per request.  (A running
-    # max over a positions grid would do it in O(size), but compiles for
-    # minutes on TPU.)  The resolving step's serve flag says served vs
-    # crash-dropped; positions at or past the replica's resolved count stay
-    # unresolved (a budget-cut or drain=False run leaves a queued tail).
-    # Carried q0 requests occupy positions [0, c0), this chunk's routed
-    # arrivals [c0, n_route).
-    keys, key_step = jax.lax.sort(
-        (key_seq, jnp.arange(n_steps, dtype=jnp.int32)), num_keys=1
-    )
-    n_res = n_srv + ndrop
+    def finish(carry, outs, *, record: bool):
+        (a_seq, mdec_seq, key_seq, srv_seq, tdone_seq,
+         adm_seq, mr_seq, pos_seq, shed_seq) = outs
+        n_steps = key_seq.shape[0]
+        (t, n_adm, rr, ph, neps, nuse, done,
+         busy, qlen, n_route, n_srv, nbat, needs,
+         fcur, rty, infl, ndrop, nshed, energy) = carry
 
-    def resolve(m, p):
-        """(served, dropped, completion time) of positions p on replicas m."""
-        q = (m * (P_sub + 1) + p).astype(key_t)
-        last = jnp.searchsorted(keys, q, side="right") - 1
-        step = key_step[jnp.clip(last, 0)]
-        done = p < n_res[m]
-        return done & srv_seq[step], done & ~srv_seq[step], tdone_seq[step]
+        # --- vectorized per-request reconstruction ----------------------
+        # Substream positions are per replica: request p on replica m resolves
+        # at the serve (or drop) whose interval [base, base + a) contains p.
+        # Sorting the steps by their (replica, base) key lines each replica's
+        # intervals up in position order, so the resolving step of (m, p) is
+        # the last key <= (m, p): one binary search per request.  (A running
+        # max over a positions grid would do it in O(size), but compiles for
+        # minutes on TPU.)  The resolving step's serve flag says served vs
+        # crash-dropped; positions at or past the replica's resolved count stay
+        # unresolved (a budget-cut or drain=False run leaves a queued tail).
+        # Carried q0 requests occupy positions [0, c0), this chunk's routed
+        # arrivals [c0, n_route).
+        keys, key_step = jax.lax.sort(
+            (key_seq, jnp.arange(n_steps, dtype=jnp.int32)), num_keys=1
+        )
+        n_res = n_srv + ndrop
 
-    # carried-queue part: positions [0, Q0) of each replica's substream
-    q0_fin = jnp.isfinite(q0_times)
-    q0_served, q0_dropped, q0_comp = resolve(
-        midx[:, None], jnp.arange(Q0)[None, :]
-    )
-    q0_served = q0_served & q0_fin
-    q0_dropped = q0_dropped & q0_fin
-    q0_lat = jnp.where(q0_served, q0_comp - q0_times, 0.0)
-    q0_miss = jnp.sum(q0_served & (q0_comp > q0_dl))
+        def resolve(m, p):
+            """(served, dropped, completion time) of positions p on
+            replicas m."""
+            q = (m * (P_sub + 1) + p).astype(key_t)
+            last = jnp.searchsorted(keys, q, side="right") - 1
+            step = key_step[jnp.clip(last, 0)]
+            done = p < n_res[m]
+            return done & srv_seq[step], done & ~srv_seq[step], tdone_seq[step]
 
-    # arrival part: scatter each routed arrival's (replica, position);
-    # shed arrivals record their would-be replica but hold no position
-    arr_server = jnp.full(size, M, dtype=jnp.int32).at[adm_seq].set(
-        mr_seq, mode="drop"
-    )
-    arr_pos = jnp.zeros(size, dtype=jnp.int32).at[adm_seq].set(
-        pos_seq, mode="drop"
-    )
-    arr_shed = jnp.zeros(size, dtype=bool).at[adm_seq].set(
-        shed_seq, mode="drop"
-    )
-    admitted = (arr_server < M) & ~arr_shed
-    arr_served, arr_dropped, arr_comp = resolve(
-        jnp.clip(arr_server, 0, M - 1), arr_pos
-    )
-    arr_served = admitted & arr_served
-    arr_dropped = admitted & arr_dropped
-    arr_lat = jnp.where(arr_served, arr_comp - arrivals, 0.0)
-    arr_miss = jnp.sum(arr_served & (arr_comp > deadlines))
+        # carried-queue part: positions [0, Q0) of each replica's substream
+        q0_fin = jnp.isfinite(q0_times)
+        q0_served, q0_dropped, q0_comp = resolve(
+            midx[:, None], jnp.arange(Q0)[None, :]
+        )
+        q0_served = q0_served & q0_fin
+        q0_dropped = q0_dropped & q0_fin
+        q0_lat = jnp.where(q0_served, q0_comp - q0_times, 0.0)
+        q0_miss = jnp.sum(q0_served & (q0_comp > q0_dl))
 
-    lat_sum = jnp.sum(q0_lat) + jnp.sum(arr_lat)
-    n_served = jnp.sum(n_srv)
-    all_lat = jnp.concatenate([q0_lat.reshape(-1), arr_lat])
-    all_ok = jnp.concatenate([q0_served.reshape(-1), arr_served])
-    bins = jnp.clip(
-        jnp.searchsorted(edges, all_lat, side="right"), 0, n_bins + 1
-    )
-    hist = jnp.zeros(n_bins + 2, dtype=i64).at[
-        jnp.where(all_ok, bins, 0)
-    ].add(all_ok.astype(i64))
+        # arrival part: scatter each routed arrival's (replica, position);
+        # shed arrivals record their would-be replica but hold no position
+        arr_server = jnp.full(size, M, dtype=jnp.int32).at[adm_seq].set(
+            mr_seq, mode="drop"
+        )
+        arr_pos = jnp.zeros(size, dtype=jnp.int32).at[adm_seq].set(
+            pos_seq, mode="drop"
+        )
+        arr_shed = jnp.zeros(size, dtype=bool).at[adm_seq].set(
+            shed_seq, mode="drop"
+        )
+        admitted = (arr_server < M) & ~arr_shed
+        arr_served, arr_dropped, arr_comp = resolve(
+            jnp.clip(arr_server, 0, M - 1), arr_pos
+        )
+        arr_served = admitted & arr_served
+        arr_dropped = admitted & arr_dropped
+        arr_lat = jnp.where(arr_served, arr_comp - arrivals, 0.0)
+        arr_miss = jnp.sum(arr_served & (arr_comp > deadlines))
 
-    n_batches = jnp.sum(srv_seq.astype(i64))  # successful serves
-    n_attempts = jnp.sum(nbat) - jnp.sum(jnp.asarray(nbat0))
-    agg = {
-        "t_final": t, "n_admitted": n_adm, "n_served": n_served,
-        "n_batches": n_batches,
-        # crashes are counted at dispatch (the chunk that launched the
-        # attempt), matching the serve-start accounting discipline
-        "n_crashes": n_attempts - n_batches,
-        "n_dropped": jnp.sum(ndrop), "n_shed": jnp.sum(nshed),
-        "n_epochs": neps, "n_steps_used": nuse,
-        "terminated": done & ~more_coming,
-        "parked": done & more_coming,
-        "incomplete": ~done & (neps < max_eps),
-        "energy": energy, "lat_sum": lat_sum,
-        "slo_miss": q0_miss + arr_miss, "hist": hist,
-        # per-replica state (stream carry + conservation checks)
-        "qlen": qlen, "busy": busy, "n_route": n_route, "n_srv": n_srv,
-        "nbat": nbat, "rr": rr, "ph": ph, "needs": needs,
-        "fcur": fcur, "rty": rty, "infl": infl,
-        "ndrop_m": ndrop, "nshed_m": nshed,
-    }
-    if not record:
-        return agg
-    rec = (a_seq, mdec_seq, arr_lat, arr_served, arr_dropped, arr_shed,
-           arr_server, arr_pos, q0_lat, q0_served, q0_dropped)
-    return agg, rec
+        lat_sum = jnp.sum(q0_lat) + jnp.sum(arr_lat)
+        n_served = jnp.sum(n_srv)
+        all_lat = jnp.concatenate([q0_lat.reshape(-1), arr_lat])
+        all_ok = jnp.concatenate([q0_served.reshape(-1), arr_served])
+        bins = jnp.clip(
+            jnp.searchsorted(edges, all_lat, side="right"), 0, n_bins + 1
+        )
+        hist = jnp.zeros(n_bins + 2, dtype=i64).at[
+            jnp.where(all_ok, bins, 0)
+        ].add(all_ok.astype(i64))
+
+        n_batches = jnp.sum(srv_seq.astype(i64))  # successful serves
+        n_attempts = jnp.sum(nbat) - jnp.sum(jnp.asarray(nbat0))
+        agg = {
+            "t_final": t, "n_admitted": n_adm, "n_served": n_served,
+            "n_batches": n_batches,
+            # crashes are counted at dispatch (the chunk that launched the
+            # attempt), matching the serve-start accounting discipline
+            "n_crashes": n_attempts - n_batches,
+            "n_dropped": jnp.sum(ndrop), "n_shed": jnp.sum(nshed),
+            "n_epochs": neps, "n_steps_used": nuse,
+            "terminated": done & ~more_coming,
+            "parked": done & more_coming,
+            "incomplete": ~done & (neps < max_eps),
+            "energy": energy, "lat_sum": lat_sum,
+            "slo_miss": q0_miss + arr_miss, "hist": hist,
+            # per-replica state (stream carry + conservation checks)
+            "qlen": qlen, "busy": busy, "n_route": n_route, "n_srv": n_srv,
+            "nbat": nbat, "rr": rr, "ph": ph, "needs": needs,
+            "fcur": fcur, "rty": rty, "infl": infl,
+            "ndrop_m": ndrop, "nshed_m": nshed,
+        }
+        if not record:
+            return agg
+        rec = (a_seq, mdec_seq, arr_lat, arr_served, arr_dropped, arr_shed,
+               arr_server, arr_pos, q0_lat, q0_served, q0_dropped)
+        return agg, rec
+
+    return carry0, step, finish
 
 
 @partial(jax.jit, static_argnames=("n_steps", "record", "mix"))
@@ -659,14 +681,15 @@ def _fleet_jit(tables, thr_gap, arrivals, deadlines, phases, beliefs, bel0,
                buf_cap, max_retries,
                rr0, ph0, busy0, nbat0, needs0, fcur0, rty0, infl0,
                more_coming, t_last, n_steps, record, mix):
-    return _fleet_scan_core(
+    carry0, step, finish = _fleet_kernel(
         tables, thr_gap, arrivals, deadlines, phases, beliefs, bel0,
         router_u, q0_times, q0_dl, draws, means, zeta, edges, fb, fmult,
         rid, t0, horizon, max_eps, drain, b_max, buf_cap, max_retries,
         rr0, ph0, busy0, nbat0, needs0, fcur0, rty0, infl0,
-        more_coming, t_last,
-        n_steps=n_steps, record=record, mix=mix,
+        more_coming, t_last, mix=mix,
     )
+    carry, outs = jax.lax.scan(step, carry0, None, length=n_steps)
+    return finish(carry, outs, record=record)
 
 
 # ---------------------------------------------------------------------------
@@ -2072,10 +2095,24 @@ def simulate_fleet_stream(
 # ---------------------------------------------------------------------------
 
 
+#: steps per iteration of the grid's event loop (at most; it divides the
+#: step count): the loop tests for active instances once per chunk
+_GRID_CHUNK = 256
+
+
 def _fleet_grid_core(tables, thrs, rids, arr, dl, ph, bel, ru, draws,
                      means, zeta, edges, t0, horizon, max_eps, drain, b_max,
                      *, n_steps: int, mix: bool):
-    """(S, P, R) fleet grid: vmap lanes x table-stacks x router ids."""
+    """(S, P, R) fleet grid: vmap lanes x table-stacks x router ids.
+
+    Returns the aggregates and, as a (1,) array, the steps the event loop
+    ran.  The loop runs outside the vmap, a chunk of steps at a time, and
+    stops at the first chunk boundary where no instance is active (an
+    instance never becomes active again) or at ``n_steps``; the rows it
+    never reaches hold what an inactive step emits.  So the aggregates are
+    bitwise those of a fixed ``n_steps``-step scan per instance
+    (`_fleet_jit`).
+    """
     M = tables.shape[1]
     q0 = jnp.full((M, 1), jnp.inf)
     busy0 = jnp.full(M, jnp.inf)
@@ -2086,22 +2123,72 @@ def _fleet_grid_core(tables, thrs, rids, arr, dl, ph, bel, ru, draws,
     fb = jnp.full((M, 1), jnp.inf)
     fmult = jnp.ones((M, 1))
 
-    def lane(a_, d_, p_, b_, u_, dr_):
-        def per_table(tab, thr):
-            def per_router(rid):
-                return _fleet_scan_core(
-                    tab, thr, a_, d_, p_, b_, b_[0], u_, q0, q0, dr_,
-                    means, zeta, edges, fb, fmult,
-                    rid, t0, horizon, max_eps, drain, b_max,
-                    _NO_BUFFER, 0,
-                    0, 0, busy0, nbat0, jnp.ones(M, dtype=bool),
-                    zm, zm, zm, False, jnp.inf,
-                    n_steps=n_steps, record=False, mix=mix,
+    def over_grid(f, in_axes=(), out_axes=0):
+        """``f(kernel, *xs)`` on one (lane, table, router) instance,
+        vmapped over the grid; each of ``xs`` carries the grid's axes at
+        its entry of ``in_axes``, the results at ``out_axes``."""
+        def lane(a_, d_, p_, b_, u_, dr_, *xs):
+            def per_table(tab, thr, *xs):
+                def per_router(rid, *xs):
+                    kernel = _fleet_kernel(
+                        tab, thr, a_, d_, p_, b_, b_[0], u_, q0, q0, dr_,
+                        means, zeta, edges, fb, fmult,
+                        rid, t0, horizon, max_eps, drain, b_max,
+                        _NO_BUFFER, 0,
+                        0, 0, busy0, nbat0, jnp.ones(M, dtype=bool),
+                        zm, zm, zm, False, jnp.inf, mix=mix,
+                    )
+                    return f(kernel, *xs)
+                return jax.vmap(per_router, (0, *in_axes), out_axes)(
+                    rids, *xs
                 )
-            return jax.vmap(per_router)(rids)
-        return jax.vmap(per_table)(tables, thrs)
+            return jax.vmap(per_table, (0, 0, *in_axes), out_axes)(
+                tables, thrs, *xs
+            )
+        lane_axes = (0,) * 6 + tuple(in_axes)
+        return lambda *xs: jax.vmap(lane, lane_axes, out_axes)(
+            arr, dl, ph, bel, ru, draws, *xs
+        )
 
-    return jax.vmap(lane)(arr, dl, ph, bel, ru, draws)
+    def start(kernel):
+        carry0, step, _ = kernel
+        # what an inactive step emits: the same step on a finished carry
+        finished = carry0[:6] + (jnp.asarray(True),) + carry0[7:]
+        _, idle_out = step(finished, None)
+        return carry0, idle_out
+
+    chunk = math.gcd(n_steps, _GRID_CHUNK)
+
+    def run_chunk(kernel, carry):
+        return jax.lax.scan(kernel[1], carry, None, length=chunk)
+
+    carry0, idle_out = over_grid(start)()
+    bufs0 = jax.tree.map(
+        lambda o: jnp.broadcast_to(o, (n_steps, *o.shape)), idle_out
+    )
+    run = over_grid(run_chunk, (0,), (0, 1))  # outs (chunk, S, P, R)
+
+    def any_active(state):
+        i, carry, _ = state
+        neps, done = carry[4], carry[6]
+        return (i < n_steps) & jnp.any(~done & (neps < max_eps))
+
+    def body(state):
+        i, carry, bufs = state
+        carry, outs = run(carry)
+        bufs = jax.tree.map(
+            lambda b, o: jax.lax.dynamic_update_slice_in_dim(b, o, i, 0),
+            bufs, outs,
+        )
+        return i + chunk, carry, bufs
+
+    i, carry, bufs = jax.lax.while_loop(
+        any_active, body, (jnp.asarray(0, dtype=jnp.int32), carry0, bufs0)
+    )
+    agg = over_grid(
+        lambda kernel, c, o: kernel[2](c, o, record=False), (0, 1)
+    )(carry, bufs)
+    return agg, i[None]
 
 
 #: jitted grid dispatchers keyed by (mesh identity, n_steps) — the
@@ -2175,6 +2262,9 @@ def run_fleet_grid(
     the derived ``w_mean`` (NaN on starved lanes), ``power``, and
     ``q_time_avg`` (time-averaged total backlog, ``lat_sum / span`` by
     Little's law — the JSQ-vs-pow2 dominance statistic).
+    ``steps_executed`` is the number of event steps the kernel ran: it
+    stops once every lane, policy and router is done, short of the
+    step-count bucket it was compiled for.
 
     ``mesh=`` shards the S axis across the mesh's *first* axis via
     `shard_map` (through distributed.meshcompat — `launch.mesh.
@@ -2291,7 +2381,7 @@ def run_fleet_grid(
     while True:
         with TraceAnnotation("repro.fleet.run", steps_run=n_steps):
             fn = _fleet_grid_fn(mesh, int(n_steps), mix)
-            out = fn(
+            out, ran = fn(
                 *dev,
                 float(t0), np.inf if horizon is None else float(horizon),
                 max_eps, bool(drain), int(b_max),
@@ -2304,10 +2394,14 @@ def run_fleet_grid(
         n_steps = min(2 * n_steps, cap)
     with TraceAnnotation("repro.fleet.post", steps_run=n_steps) as post:
         out = {k: np.asarray(v) for k, v in out.items()}
-        # the vmapped scan runs every lane, policy and router to the longest
-        post.set_metadata(steps_used=int(out["n_steps_used"].max()))
+        # the vmapped loop runs every lane, policy and router in lockstep
+        # until the last is done (the most any device's lanes needed)
+        steps_executed = int(np.asarray(ran).max())
+        post.set_metadata(steps_used=int(out["n_steps_used"].max()),
+                          steps_executed=steps_executed)
         if pad_s:
             out = {k: v[:S] for k, v in out.items()}
+        out["steps_executed"] = steps_executed
         out["hist_edges"] = edges
         with np.errstate(invalid="ignore", divide="ignore"):
             span = out["t_final"] - t0

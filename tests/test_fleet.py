@@ -476,6 +476,70 @@ class TestFleetGrid:
         assert np.isclose(out["energy"][1, 1, i], ref.energy)
         assert np.isclose(out["t_final"][1, 1, i], ref.t_final)
 
+    @pytest.mark.parametrize(
+        "lens, max_epochs, dispatches",
+        [((5, 900, 400), None, 1), ((300, 600), 400, 2), ((300, 900), 250, 1)],
+        ids=["uneven-lanes", "escalated", "budget-cut"],
+    )
+    def test_early_exit_matches_fixed_length_scan(
+        self, monkeypatch, lens, max_epochs, dispatches
+    ):
+        """The grid's loop stops once every instance is done; each
+        aggregate is bitwise `_fleet_jit`'s over the full step count."""
+        from repro.serving import fleet
+        from repro.serving.compiled import default_hist_edges
+
+        sizes = []
+        grid_fn = fleet._fleet_grid_fn
+
+        def spy(mesh, n_steps, mix):
+            sizes.append(n_steps)
+            return grid_fn(mesh, n_steps, mix)
+
+        monkeypatch.setattr(fleet, "_fleet_grid_fn", spy)
+        rng = np.random.default_rng(5)
+        arr = pad_arrivals_batch(
+            [np.cumsum(rng.exponential(1.0 / (4 * LAM), n)) for n in lens]
+        )
+        policies = np.stack([TABLE, q_policy(10, 96, BMAX)])
+        routers = ("jsq", "batch_aware")
+        M = 4
+        out = run_fleet_grid(
+            policies, arr, routers=routers, n_replicas=M, means=MEANS,
+            zeta=ENERGY, b_max=BMAX, max_epochs=max_epochs, router_seed=3,
+        )
+        assert len(sizes) == dispatches
+        if max_epochs is not None:  # the budget cuts some instance short
+            assert (out["n_epochs"] == max_epochs).any()
+        n_steps = sizes[-1]
+        assert out["n_steps_used"].max() <= out["steps_executed"] < n_steps
+
+        S, N = arr.shape
+        max_eps = (
+            2 * max(lens) + M + 4 if max_epochs is None else max_epochs
+        )
+        ru = np.random.default_rng(3).random((S, N, 2))
+        q0 = np.full((M, 1), np.inf)
+        zm = np.zeros(M, dtype=np.int64)
+        for p, pol in enumerate(policies):
+            tab = np.repeat(pol[None, None, :], M, axis=0)
+            for s in range(S):
+                for r, router in enumerate(routers):
+                    agg = fleet._fleet_jit(
+                        tab, threshold_gaps(tab), arr[s], np.full(N, np.inf),
+                        np.zeros(N, dtype=np.int64), np.zeros((1, 1)),
+                        np.zeros(1), ru[s], q0, q0, np.ones(1), MEANS, ENERGY,
+                        default_hist_edges(MEANS), q0, np.ones((M, 1)),
+                        fleet.router_id(router), 0.0, np.inf, max_eps, True,
+                        BMAX, fleet._NO_BUFFER, 0, 0, 0, np.full(M, np.inf),
+                        zm, np.ones(M, dtype=bool), zm, zm, zm, False,
+                        np.inf, n_steps=n_steps, record=False, mix=False,
+                    )
+                    for k, v in agg.items():
+                        want, got = np.asarray(v), out[k][s, p, r]
+                        assert want.dtype == got.dtype, k
+                        assert want.tobytes() == got.tobytes(), (k, s, p, r)
+
     def test_one_device_mesh_parity(self):
         from repro.launch.mesh import make_sim_mesh
 

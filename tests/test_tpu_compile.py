@@ -143,6 +143,24 @@ def test_fleet_jit_m8(one_chip):
     _fits(c)
 
 
+def test_fleet_grid_bursty(one_chip):
+    """The early-exit fleet grid at the bursty benchmark cell's shapes:
+    16 lanes x 1 table x 2 routers, M = 8, 2^18 arrival slots."""
+    S, P, R, M, n = 16, 1, 2, 8, 1 << 18
+    f64, i64 = jnp.float64, jnp.int64
+    L = S_MAX + 1
+    args = _shapes(
+        one_chip, ((P, M, 1, L), i64), ((P, M, 1, L), i64), ((R,), i64),
+        ((S, n), f64), ((S, n), f64), ((S, n), i64), ((S, 1, 1), f64),
+        ((S, n, 2), f64), ((S, 1), f64), ((B_MAX + 1,), f64),
+        ((B_MAX + 1,), f64), ((65,), f64),
+    )
+    c = fleet._fleet_grid_fn(None, 786432, False).lower(
+        *args, 0.0, np.inf, 2 * n + M + 4, True, B_MAX,
+    ).compile()
+    _fits(c)
+
+
 def test_poisson_times_jax(one_chip):
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
     c = jax.jit(
