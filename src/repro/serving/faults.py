@@ -97,6 +97,47 @@ class FaultSchedule:
         return float(self.mult[m, min(attempt, self.mult.shape[1] - 1)])
 
 
+def stack_schedules(schedules, n_replicas: int):
+    """S schedules (one per lane) -> ``(fb, fmult, max_retries)``.
+
+    ``fb`` is (S, M, 2F) with F the most down intervals of any replica of
+    any lane, padded with +inf (at least one column, so the kernel's
+    boundary gather never indexes an empty axis); ``fmult`` is (S, M, D)
+    with each row padded by repeating its last multiplier, which is the
+    kernel's own clip of the attempt index.  Every lane must cover the
+    fleet's M replicas and share one ``max_retries``.
+    """
+    schedules = list(schedules)
+    if not schedules:
+        raise ValueError("stack_schedules needs at least one schedule")
+    for s in schedules:
+        if not isinstance(s, FaultSchedule):
+            raise TypeError(
+                "faults= must be a FaultSchedule (FaultModel.materialize())"
+            )
+        if s.n_replicas != n_replicas:
+            raise ValueError(
+                f"fault schedule covers {s.n_replicas} replicas, "
+                f"fleet has {n_replicas}"
+            )
+    retries = {s.max_retries for s in schedules}
+    if len(retries) != 1:
+        raise ValueError(
+            f"every lane's schedule must share max_retries; got {sorted(retries)}"
+        )
+    S, M = len(schedules), n_replicas
+    width = max(1, max(s.bounds.shape[1] for s in schedules))
+    depth = max(s.mult.shape[1] for s in schedules)
+    fb = np.full((S, M, width), np.inf)
+    fmult = np.empty((S, M, depth))
+    for i, s in enumerate(schedules):
+        fb[i, :, : s.bounds.shape[1]] = s.bounds
+        d = s.mult.shape[1]
+        fmult[i, :, :d] = s.mult
+        fmult[i, :, d:] = s.mult[:, -1:]
+    return fb, fmult, retries.pop()
+
+
 @dataclasses.dataclass(frozen=True)
 class FaultModel:
     """Availability / straggler law; materialize() samples a schedule."""

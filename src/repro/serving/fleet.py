@@ -58,8 +58,9 @@ FRONT with bounded retries, then drop — crashed attempts burn prorated
 energy, and ``buffer=B`` bounds each replica's waiting room (overflow
 arrivals shed at admission).  All of it runs identically in the compiled
 kernel, `PythonFleet`, and `FleetStream` (fault cursors, retry counters
-and in-flight requeues carry across chunks); `verify_faults` certifies
-the contract per router and arrival family.
+and in-flight requeues carry across chunks), and in `run_fleet_grid`,
+whose lanes each take their own schedule; `verify_faults` certifies the
+contract per router and arrival family.
 """
 from __future__ import annotations
 
@@ -721,20 +722,10 @@ def _prep_faults(faults, M: int):
     """
     if faults is None:
         return np.full((M, 1), np.inf), np.ones((M, 1)), 0
-    from .faults import FaultSchedule
+    from .faults import stack_schedules
 
-    if not isinstance(faults, FaultSchedule):
-        raise TypeError(
-            "faults= must be a FaultSchedule (FaultModel.materialize())"
-        )
-    if faults.n_replicas != M:
-        raise ValueError(
-            f"fault schedule covers {faults.n_replicas} replicas, fleet has {M}"
-        )
-    fb = faults.bounds
-    if fb.shape[1] == 0:
-        fb = np.full((M, 1), np.inf)
-    return fb, faults.mult, int(faults.max_retries)
+    fb, fmult, max_retries = stack_schedules([faults], M)
+    return fb[0], fmult[0], max_retries
 
 
 def _prep_inputs(
@@ -2102,7 +2093,7 @@ _GRID_CHUNK = 256
 
 def _fleet_grid_core(tables, thrs, rids, arr, dl, ph, bel, ru, draws,
                      means, zeta, edges, t0, horizon, max_eps, drain, b_max,
-                     *, n_steps: int, mix: bool):
+                     *faults, n_steps: int, mix: bool):
     """(S, P, R) fleet grid: vmap lanes x table-stacks x router ids.
 
     Returns the aggregates and, as a (1,) array, the steps the event loop
@@ -2112,29 +2103,42 @@ def _fleet_grid_core(tables, thrs, rids, arr, dl, ph, bel, ru, draws,
     never reaches hold what an inactive step emits.  So the aggregates are
     bitwise those of a fixed ``n_steps``-step scan per instance
     (`_fleet_jit`).
+
+    ``faults`` is empty for a fault-free grid, whose kernels get the
+    constants all-+inf boundaries, unit multipliers and ``max_retries``
+    0; or ``(fb, fmult, max_retries)``: the lanes' (S, M, 2F) boundaries
+    and (S, M, D) multipliers (`faults.stack_schedules`), vmapped with
+    the other lane arrays, and the retry bound every lane shares.
     """
     M = tables.shape[1]
     q0 = jnp.full((M, 1), jnp.inf)
     busy0 = jnp.full(M, jnp.inf)
     nbat0 = jnp.zeros(M, dtype=jnp.int64)
     zm = jnp.zeros(M, dtype=jnp.int64)
-    # the grid runs fault-free (faults are a per-lane simulate_fleet /
-    # FleetStream concern): all-+inf boundaries, unit multipliers
-    fb = jnp.full((M, 1), jnp.inf)
-    fmult = jnp.ones((M, 1))
+    if faults:
+        fb, fmult, max_retries = faults
+        lane_faults = (fb, fmult)
+    else:
+        lane_faults, max_retries = (), 0
+        fb0 = jnp.full((M, 1), jnp.inf)
+        fmult0 = jnp.ones((M, 1))
+    nf = len(lane_faults)
 
     def over_grid(f, in_axes=(), out_axes=0):
         """``f(kernel, *xs)`` on one (lane, table, router) instance,
         vmapped over the grid; each of ``xs`` carries the grid's axes at
         its entry of ``in_axes``, the results at ``out_axes``."""
         def lane(a_, d_, p_, b_, u_, dr_, *xs):
+            fb_, fm_ = xs[:nf] if nf else (fb0, fmult0)
+            xs = xs[nf:]
+
             def per_table(tab, thr, *xs):
                 def per_router(rid, *xs):
                     kernel = _fleet_kernel(
                         tab, thr, a_, d_, p_, b_, b_[0], u_, q0, q0, dr_,
-                        means, zeta, edges, fb, fmult,
+                        means, zeta, edges, fb_, fm_,
                         rid, t0, horizon, max_eps, drain, b_max,
-                        _NO_BUFFER, 0,
+                        _NO_BUFFER, max_retries,
                         0, 0, busy0, nbat0, jnp.ones(M, dtype=bool),
                         zm, zm, zm, False, jnp.inf, mix=mix,
                     )
@@ -2145,9 +2149,9 @@ def _fleet_grid_core(tables, thrs, rids, arr, dl, ph, bel, ru, draws,
             return jax.vmap(per_table, (0, 0, *in_axes), out_axes)(
                 tables, thrs, *xs
             )
-        lane_axes = (0,) * 6 + tuple(in_axes)
+        lane_axes = (0,) * (6 + nf) + tuple(in_axes)
         return lambda *xs: jax.vmap(lane, lane_axes, out_axes)(
-            arr, dl, ph, bel, ru, draws, *xs
+            arr, dl, ph, bel, ru, draws, *lane_faults, *xs
         )
 
     def start(kernel):
@@ -2196,8 +2200,8 @@ def _fleet_grid_core(tables, thrs, rids, arr, dl, ph, bel, ru, draws,
 _FLEET_GRID_CACHE: dict = {}
 
 
-def _fleet_grid_fn(mesh, n_steps: int, mix: bool):
-    key = (None if mesh is None else id(mesh), n_steps, mix)
+def _fleet_grid_fn(mesh, n_steps: int, mix: bool, faults: bool = False):
+    key = (None if mesh is None else id(mesh), n_steps, mix, faults)
     fn = _FLEET_GRID_CACHE.get(key)
     if fn is not None:
         return fn
@@ -2212,10 +2216,12 @@ def _fleet_grid_fn(mesh, n_steps: int, mix: bool):
         core = shard_map(
             core, mesh=mesh,
             # lanes (S-leading arrays) shard over the mesh's first axis;
-            # tables / router ids / service constants replicate
+            # tables / router ids / service constants replicate; a fault
+            # schedule's boundaries and multipliers are lane arrays, its
+            # retry bound a constant
             in_specs=(rep, rep, rep, P(axis), P(axis), P(axis), P(axis),
                       P(axis), P(axis), rep, rep, rep, rep, rep, rep, rep,
-                      rep),
+                      rep) + ((P(axis), P(axis), rep) if faults else ()),
             out_specs=P(axis),
         )
     fn = jax.jit(core)
@@ -2244,6 +2250,7 @@ def run_fleet_grid(
     hist_edges=None,
     router_seed: int = 0,
     mesh=None,
+    faults=None,
 ):
     """The fleet sweep: (seeds x scenarios) traces x policies x routers.
 
@@ -2266,12 +2273,24 @@ def run_fleet_grid(
     stops once every lane, policy and router is done, short of the
     step-count bucket it was compiled for.
 
+    ``faults`` — None (fault-free), one `serving.faults.FaultSchedule`
+    for every lane, or a sequence of S schedules, one per lane, each over
+    the fleet's M replicas and all with one ``max_retries``.  Every
+    instance of lane s then runs ``simulate_fleet(faults=schedule_s)``'s
+    degraded-mode semantics, bitwise (given the same draws and router
+    uniforms), and ``n_crashes`` / ``n_dropped`` count its crashed
+    attempts and dropped requests.  The schedules are stacked into lane
+    arrays (`faults.stack_schedules`); the fault-free grid is a program
+    of its own that compiles the constants in.
+
     ``mesh=`` shards the S axis across the mesh's *first* axis via
     `shard_map` (through distributed.meshcompat — `launch.mesh.
     make_sim_mesh()` builds the 1-D all-devices mesh); S is padded to a
     device multiple by repeating the first lane and trimmed on return.
     """
-    with TraceAnnotation("repro.fleet.prepare", lanes=len(arrivals)):
+    with TraceAnnotation(
+        "repro.fleet.prepare", lanes=len(arrivals)
+    ) as prepare:
         tables = np.asarray(tables, dtype=np.int64)
         if tables.ndim == 2:
             if n_replicas is None:
@@ -2354,8 +2373,28 @@ def run_fleet_grid(
             np.asarray(bel, dtype=np.float64) if mix else np.zeros((S, 1, 1))
         )
         n_arr_max = int(np.isfinite(arr).sum(axis=1).max())
+        lane_faults = ()
+        n_bnd = n_bounds = 0  # the most of any lane, and over all lanes
+        if faults is not None:
+            from .faults import FaultSchedule, stack_schedules
+
+            if isinstance(faults, FaultSchedule):
+                faults = [faults] * S
+            if len(faults) != S:
+                raise ValueError(
+                    f"{len(faults)} fault schedules for {S} lanes"
+                )
+            fb, fmult, max_retries = stack_schedules(faults, M)
+            finite = np.isfinite(fb).sum(axis=(1, 2))
+            n_bnd, n_bounds = int(finite.max()), int(finite.sum())
+            lane_faults = (fb, fmult)
+        prepare.set_metadata(fault_bounds=n_bounds)
+        # crashes re-serve their batch and repairs wake queued replicas —
+        # at most two extra epochs per finite fault boundary
         max_eps = (
-            2 * n_arr_max + M + 4 if max_epochs is None else int(max_epochs)
+            2 * n_arr_max + M + 4 + 2 * n_bnd
+            if max_epochs is None
+            else int(max_epochs)
         )
         # mesh: pad the lane axis to a device multiple (repeat lane 0), trim
         pad_s = 0
@@ -2368,7 +2407,8 @@ def run_fleet_grid(
                 arr, dl, ph, bel_g, ru, draws = map(
                     _pad, (arr, dl, ph, bel_g, ru, draws)
                 )
-        cap = _bucket(2 * (n_arr_max + max_eps) + 2 * M + 8)
+                lane_faults = tuple(map(_pad, lane_faults))
+        cap = _bucket(2 * (n_arr_max + max_eps + n_bnd) + 2 * M + 8)
         n_steps = min(
             _bucket(max(256, (5 * n_arr_max) // 2 + 2 * M + 8)), cap
         )
@@ -2378,13 +2418,17 @@ def run_fleet_grid(
             for x in (tables, thrs, rids, arr, dl, ph, bel_g, ru, draws,
                       means, zeta_a, edges)
         )
+        fault_args = (
+            tuple(jnp.asarray(x) for x in lane_faults) + (max_retries,)
+            if lane_faults else ()
+        )
     while True:
         with TraceAnnotation("repro.fleet.run", steps_run=n_steps):
-            fn = _fleet_grid_fn(mesh, int(n_steps), mix)
+            fn = _fleet_grid_fn(mesh, int(n_steps), mix, bool(fault_args))
             out, ran = fn(
                 *dev,
                 float(t0), np.inf if horizon is None else float(horizon),
-                max_eps, bool(drain), int(b_max),
+                max_eps, bool(drain), int(b_max), *fault_args,
             )
             done = n_steps >= cap or not bool(
                 np.asarray(out["incomplete"]).any()
@@ -2397,10 +2441,12 @@ def run_fleet_grid(
         # the vmapped loop runs every lane, policy and router in lockstep
         # until the last is done (the most any device's lanes needed)
         steps_executed = int(np.asarray(ran).max())
-        post.set_metadata(steps_used=int(out["n_steps_used"].max()),
-                          steps_executed=steps_executed)
         if pad_s:
             out = {k: v[:S] for k, v in out.items()}
+        post.set_metadata(steps_used=int(out["n_steps_used"].max()),
+                          steps_executed=steps_executed,
+                          crashes=int(out["n_crashes"].sum()),
+                          dropped=int(out["n_dropped"].sum()))
         out["steps_executed"] = steps_executed
         out["hist_edges"] = edges
         with np.errstate(invalid="ignore", divide="ignore"):

@@ -3,9 +3,11 @@ kernel per arrival mode, Python-reference router agreement per routing
 policy, conservation/dominance invariants, snapshot()/restore() through
 router state, chunked streaming vs materialized record, the record-slot
 cap, the count-zero metrics convention, and the mesh-sharded grid."""
+import dataclasses
 import os
 import subprocess
 import sys
+from functools import partial
 from pathlib import Path
 
 import numpy as np
@@ -14,6 +16,7 @@ import pytest
 from repro.core import GOOGLENET_P4_ENERGY, GOOGLENET_P4_LATENCY, ServiceModel
 from repro.core.policies import q_policy
 from repro.serving import (
+    FaultSchedule,
     FleetStream,
     PythonFleet,
     ServingMetrics,
@@ -454,6 +457,44 @@ class TestFleetBeliefLane:
             )
 
 
+_FAULT_M = 4
+#: grid aggregate -> simulate_fleet's FleetResult field
+_GRID_FIELDS = {
+    "t_final": "t_final", "n_served": "n_served", "n_batches": "n_batches",
+    "n_epochs": "n_epochs", "n_admitted": "n_admitted", "energy": "energy",
+    "lat_sum": "lat_sum", "slo_miss": "slo_miss", "terminated": "terminated",
+    "hist": "hist", "n_crashes": "n_crashes", "n_dropped": "n_dropped",
+    "n_shed": "n_shed", "qlen": "qlen", "busy": "busy",
+    "n_route": "n_routed", "n_srv": "n_served_m",
+}
+
+
+def _faulted_grid_inputs():
+    """Three lanes (Poisson, MMPP2, Poisson) of a 4-replica fleet with 0,
+    1 and 3 outages: stragglers in the first and last, and in the last a
+    replica that crashes, is repaired, and crashes its retry at once, so
+    max_retries = 1 drops the batch."""
+    lam = _FAULT_M * LAM
+    traces = [_trace("poisson", seed=3, lam=lam),
+              _trace("mmpp2", seed=4, lam=lam),
+              _trace("poisson", seed=5, lam=lam)]
+    arr = pad_arrivals_batch(traces)
+    rng = np.random.default_rng(9)
+    strag = np.where(rng.random((2, _FAULT_M, 64)) < 0.2, 3.0, 1.0)
+    inf = np.inf
+    none = np.full((_FAULT_M, 0), inf)
+    one = np.array([[40.0, 70.0], [inf, inf], [inf, inf], [inf, inf]])
+    three = np.array([[20.0, 35.0, 35.5, 50.0], [inf] * 4, [inf] * 4,
+                      [90.0, 100.0, inf, inf]])
+    schedules = [
+        FaultSchedule(bounds=none, mult=strag[0], max_retries=1),
+        FaultSchedule(bounds=one, mult=np.ones((_FAULT_M, 1)), max_retries=1),
+        FaultSchedule(bounds=three, mult=strag[1], max_retries=1),
+    ]
+    policies = np.stack([TABLE, q_policy(10, 96, BMAX)])
+    return arr, policies, schedules
+
+
 class TestFleetGrid:
     def test_grid_cell_matches_simulate_fleet(self):
         traces = [_trace("poisson", seed=s, lam=4 * LAM) for s in range(2)]
@@ -492,9 +533,9 @@ class TestFleetGrid:
         sizes = []
         grid_fn = fleet._fleet_grid_fn
 
-        def spy(mesh, n_steps, mix):
+        def spy(mesh, n_steps, *rest):
             sizes.append(n_steps)
-            return grid_fn(mesh, n_steps, mix)
+            return grid_fn(mesh, n_steps, *rest)
 
         monkeypatch.setattr(fleet, "_fleet_grid_fn", spy)
         rng = np.random.default_rng(5)
@@ -539,6 +580,93 @@ class TestFleetGrid:
                         want, got = np.asarray(v), out[k][s, p, r]
                         assert want.dtype == got.dtype, k
                         assert want.tobytes() == got.tobytes(), (k, s, p, r)
+
+    def test_faulted_grid_matches_simulate_fleet_bitwise(self):
+        """Each lane's own schedule: every aggregate of every (lane,
+        policy, router) instance is simulate_fleet(faults=that schedule)'s,
+        bit for bit, given the same draws and router uniforms."""
+        arr, policies, schedules = _faulted_grid_inputs()
+        out = run_fleet_grid(
+            policies, arr, routers=ROUTER_NAMES, n_replicas=_FAULT_M,
+            means=MEANS, zeta=ENERGY, b_max=BMAX, router_seed=11,
+            faults=schedules,
+        )
+        # the schedules exercise what they should: no crash in the first
+        # lane, some in the second, in every instance of the last, and a
+        # drop after max_retries there
+        assert (out["n_crashes"][0] == 0).all()
+        assert (out["n_crashes"][1] > 0).any()
+        assert (out["n_crashes"][2] > 0).all()
+        assert (out["n_dropped"][2] > 0).any()
+        ru = np.random.default_rng(11).random(arr.shape + (2,))
+        for s, sch in enumerate(schedules):
+            for p, pol in enumerate(policies):
+                for r, router in enumerate(ROUTER_NAMES):
+                    ref = simulate_fleet(
+                        np.tile(pol[None], (_FAULT_M, 1)), arr[s],
+                        router=router, means=MEANS, zeta=ENERGY, b_max=BMAX,
+                        router_u=ru[s], faults=sch,
+                    )
+                    for key, field in _GRID_FIELDS.items():
+                        want = np.asarray(getattr(ref, field))
+                        got = out[key][s, p, r]
+                        assert want.tobytes() == np.asarray(
+                            got, dtype=want.dtype).tobytes(), (key, s, p, r)
+
+    def test_one_schedule_serves_every_lane(self):
+        arr, policies, schedules = _faulted_grid_inputs()
+        kw = dict(routers=("jsq", "batch_aware"), n_replicas=_FAULT_M,
+                  means=MEANS, zeta=ENERGY, b_max=BMAX)
+        one = run_fleet_grid(policies, arr, faults=schedules[2], **kw)
+        each = run_fleet_grid(policies, arr, faults=[schedules[2]] * 3, **kw)
+        for k, v in one.items():
+            assert np.array_equal(v, each[k], equal_nan=True), k
+
+    def test_fault_schedules_are_checked(self):
+        arr, policies, schedules = _faulted_grid_inputs()
+        kw = dict(n_replicas=_FAULT_M, means=MEANS, b_max=BMAX)
+        with pytest.raises(ValueError, match="2 fault schedules for 3"):
+            run_fleet_grid(policies, arr, faults=schedules[:2], **kw)
+        with pytest.raises(ValueError, match="max_retries"):
+            run_fleet_grid(policies, arr, faults=schedules[:2] + [
+                dataclasses.replace(schedules[2], max_retries=2)], **kw)
+        with pytest.raises(ValueError, match="covers 3 replicas"):
+            run_fleet_grid(
+                policies, arr, faults=FaultSchedule.none(3), **kw)
+
+    def test_fault_free_grid_lowers_the_constant_program(self):
+        """faults=None compiles the grid whose kernels take the constants
+        +inf boundaries, unit multipliers and max_retries 0: no lane
+        arrays, no further parameters."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.serving import fleet
+
+        M, S, N = _FAULT_M, 3, 64
+        f64, i64 = jnp.float64, jnp.int64
+        shapes = [
+            jax.ShapeDtypeStruct(s, d) for s, d in (
+                ((2, M, 1, 97), i64), ((2, M, 1, 97), i64), ((2,), i64),
+                ((S, N), f64), ((S, N), f64), ((S, N), i64),
+                ((S, 1, 1), f64), ((S, N, 2), f64), ((S, 1), f64),
+                ((BMAX + 1,), f64), ((BMAX + 1,), f64), ((65,), f64),
+            )
+        ]
+        scalars = (0.0, np.inf, 2 * N + M + 4, True, BMAX)
+
+        def text(fn, *extra):
+            return fn.lower(*shapes, *scalars, *extra).as_text()
+
+        free = text(fleet._fleet_grid_fn(None, 256, False))
+        consts = text(jax.jit(partial(
+            fleet._fleet_grid_core, n_steps=256, mix=False)))
+        assert free == consts
+        lanes = [jax.ShapeDtypeStruct((S, M, 2), f64),
+                 jax.ShapeDtypeStruct((S, M, 4), f64)]
+        faulted = text(fleet._fleet_grid_fn(None, 256, False, True),
+                       *lanes, 2)
+        assert faulted != free
 
     def test_one_device_mesh_parity(self):
         from repro.launch.mesh import make_sim_mesh
@@ -599,3 +727,43 @@ def test_fleet_grid_sharded_8dev():
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "OK sharded == plain" in r.stdout
+
+
+_FAULT_SHARD_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import sys
+import numpy as np, jax
+sys.path.insert(0, "tests")
+from test_fleet import (_FAULT_M, _faulted_grid_inputs, BMAX, ENERGY, MEANS,
+                        ROUTER_NAMES)
+from repro.launch.mesh import make_sim_mesh
+from repro.serving import run_fleet_grid
+
+assert jax.device_count() == 8
+# 3 lanes on 8 devices: the lane-0 padding repeats lane 0's schedule too
+arr, policies, schedules = _faulted_grid_inputs()
+kw = dict(routers=ROUTER_NAMES, n_replicas=_FAULT_M, means=MEANS,
+          zeta=ENERGY, b_max=BMAX, faults=schedules)
+plain = run_fleet_grid(policies, arr, **kw)
+shard = run_fleet_grid(policies, arr, mesh=make_sim_mesh(), **kw)
+assert plain["n_crashes"].sum() > 0 and plain["n_dropped"].sum() > 0
+for k, v in plain.items():
+    assert np.array_equal(v, shard[k], equal_nan=True), k
+print("OK faulted sharded == plain")
+"""
+
+
+def test_faulted_fleet_grid_sharded_8dev():
+    r = subprocess.run(
+        [sys.executable, "-c", _FAULT_SHARD_SCRIPT],
+        cwd=ROOT,
+        env={"PYTHONPATH": str(ROOT / "src"),
+             "PATH": os.environ.get("PATH", ""),
+             "HOME": os.environ.get("HOME", ""), **_JAX_ENV},
+        capture_output=True,
+        text=True,
+        timeout=500,
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "OK faulted sharded == plain" in r.stdout

@@ -28,23 +28,29 @@ HBM_BYTES = 16 * 2**30  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        topo = topologies.get_topology_desc(
+        yield topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shapes(sharding, *specs):
@@ -157,6 +163,53 @@ def test_fleet_grid_bursty(one_chip):
     )
     c = fleet._fleet_grid_fn(None, 786432, False).lower(
         *args, 0.0, np.inf, 2 * n + M + 4, True, B_MAX,
+    ).compile()
+    _fits(c)
+
+
+def _grid_args(sharding, lane_sharding, S, faults):
+    """The fleet grid's arguments at the bursty cells' shapes (M = 8, 1
+    table, 2 routers, 2^18 slots) for S lanes, plus a failover schedule's
+    (S, 8, 2) boundaries and (S, 8, 4096) multipliers."""
+    P, R, M, n = 1, 2, 8, 1 << 18
+    f64, i64 = jnp.float64, jnp.int64
+    L = S_MAX + 1
+    rep = _shapes(sharding, ((P, M, 1, L), i64), ((P, M, 1, L), i64),
+                  ((R,), i64))
+    lanes = _shapes(lane_sharding, ((S, n), f64), ((S, n), f64),
+                    ((S, n), i64), ((S, 1, 1), f64), ((S, n, 2), f64),
+                    ((S, 1), f64))
+    consts = _shapes(sharding, ((B_MAX + 1,), f64), ((B_MAX + 1,), f64),
+                     ((65,), f64))
+    args = rep + lanes + consts + [0.0, np.inf, 2 * n + M + 8, True, B_MAX]
+    if faults:
+        args += _shapes(lane_sharding, ((S, M, 2), f64),
+                        ((S, M, 4096), f64)) + [2]
+    return args
+
+
+def test_fleet_grid_failover(one_chip):
+    """The faulted fleet grid at the failover cell's shapes: 16 lanes,
+    each with its own boundaries and straggler multipliers."""
+    c = fleet._fleet_grid_fn(None, 786432, False, True).lower(
+        *_grid_args(one_chip, one_chip, 16, True)
+    ).compile()
+    _fits(c)
+
+
+@pytest.mark.parametrize("faults", [False, True], ids=["bursty", "failover"])
+def test_fleet_grid_lanes_mesh_2x2(topo, faults):
+    """The lane-sharded grid over the described 2x2 host, 16 lanes per
+    chip as in the four-chip cell; with faults, the schedules shard with
+    the lanes."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    rep = NamedSharding(mesh, P())
+    lanes = NamedSharding(mesh, P("data"))
+    c = fleet._fleet_grid_fn(mesh, 786432, False, faults).lower(
+        *_grid_args(rep, lanes, 64, faults)
     ).compile()
     _fits(c)
 

@@ -170,3 +170,38 @@ def test_simulator_spans_count_steps(tmp_path, kind):
         assert (post[3]["steps_used"] <= post[3]["steps_executed"]
                 <= post[3]["steps_run"])
         assert post[3]["steps_executed"] == traced["steps_executed"]
+
+
+def test_faulted_fleet_spans_count_faults(tmp_path):
+    """A faulted fleet grid: ``prepare`` counts the lanes' finite fault
+    boundaries, ``post`` the grid's crashed attempts and dropped requests,
+    and the results are bitwise the same with the profiler on and off."""
+    from repro.serving import FaultSchedule
+
+    table = np.asarray(sweep_solve([_spec(0.7)])[0].policy)[None]
+    arr = _arrivals(2, 4092, 8 * 0.7 * BMAX / float(SVC.mean(BMAX)))
+    span = float(arr[0, 4091])
+    inf = np.inf
+    bounds = np.full((8, 4), inf)
+    bounds[0] = (0.3 * span, 0.3 * span + 20.0, 0.3 * span + 20.5, inf)
+    faults = [
+        FaultSchedule(bounds=bounds, mult=np.ones((8, 1)), max_retries=0),
+        FaultSchedule(bounds=bounds[:, :2],
+                      mult=np.where(np.arange(64) % 7 == 0, 2.0, 1.0)[None]
+                      .repeat(8, axis=0), max_retries=0),
+    ]
+
+    def call():
+        return run_fleet_grid(
+            table, arr, routers=("jsq", "batch_aware"), n_replicas=8,
+            means=MEANS, zeta=ENERGY, b_max=BMAX, faults=faults,
+        )
+
+    plain = call()
+    traced, spans = _profiled(tmp_path, call)
+    _same(plain, traced)
+    (prep,) = _named(spans, "repro.fleet.prepare")
+    (post,) = _named(spans, "repro.fleet.post")
+    assert prep[3]["fault_bounds"] == 5
+    assert post[3]["crashes"] == int(traced["n_crashes"].sum()) > 0
+    assert post[3]["dropped"] == int(traced["n_dropped"].sum()) > 0
