@@ -49,6 +49,7 @@ live arrival left either drains the queue in b_max-capped batches
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -326,17 +327,31 @@ class AdaptiveLane:
         )
 
 
-def _scan_core(
+def _admissible(arrivals, horizon):
+    """The arrivals the kernel admits from: +inf from the horizon on."""
+    return jnp.where(arrivals < horizon, arrivals, jnp.inf)
+
+
+def _scan_kernel(
     table, arrivals, deadlines, phases, beliefs, draws, means, zeta, edges,
     t0, horizon, max_eps, drain, b_max, adap=None, buffer_cap=None,
     shed=None,
-    *, n_steps: int, record: bool, mix: bool = False, adaptive: bool = False,
-    qman: bool = False,
+    *, record: bool, mix: bool = False, adaptive: bool = False,
+    qman: bool = False, arr_adm=None,
 ):
     """The event kernel: one scan step == one admission OR one epoch.
 
+    Returns its three pieces ``(carry0, step, finish)``: the carry before
+    the first step, ``step(carry, _) -> (carry, out)`` (a `lax.scan`
+    body), and ``finish(carry, outs)``, the per-request reconstruction and
+    aggregates from the final carry and the stacked per-step outputs.
+    `_scan_core` scans a fixed number of steps; `_lockstep_grid` stops
+    once no instance of its grid is active.
+
     Pure jax function; shapes only (no jit here — callers jit/vmap it).
-    `arrivals` must be sorted with at least one trailing +inf sentinel.
+    `arrivals` must be sorted with at least one trailing +inf sentinel;
+    ``arr_adm``, when given, is `_admissible` of them, computed once by a
+    caller that builds the kernel inside a loop.
     ``table`` is a (K, L) phase-indexed stack (K = 1 for plain policies)
     and ``phases`` the per-arrival phase ints aligned with ``arrivals``;
     the active row is the phase of the last admitted arrival — the Python
@@ -388,14 +403,17 @@ def _scan_core(
         by mapping each request slot to the serve epoch that completed it
         (a searchsorted into the cumulative batch sizes).
 
-    A lane that exhausts n_steps before terminating or filling its epoch
+    A lane that exhausts its steps before terminating or filling its epoch
     budget reports ``incomplete``; callers re-dispatch at a doubled step
     count (the scan is deterministic, so the prefix replays identically).
+    An inactive step (``done``, or the epoch budget spent) changes no
+    carry and serves nothing: ``a = 0``, and the resolved count stays.
     """
     L = table.shape[-1]
     size = arrivals.shape[0]
     n_bins = edges.shape[0] - 1
-    arr_adm = jnp.where(arrivals < horizon, arrivals, jnp.inf)
+    if arr_adm is None:
+        arr_adm = _admissible(arrivals, horizon)
     n_draws = draws.shape[0]
     i64 = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
 
@@ -561,10 +579,23 @@ def _scan_core(
         jnp.asarray(False),
         jnp.asarray(0.0, dtype=jnp.float64),
     ), ad_state0 if adaptive else None, qm0)
-    carry, outs = jax.lax.scan(step, carry0, None, length=n_steps, unroll=4)
+    return carry0, step, partial(
+        _finish, arrivals, deadlines, edges, max_eps,
+        record=record, adaptive=adaptive, qman=qman,
+    )
+
+
+def _finish(arrivals, deadlines, edges, max_eps, carry, outs, *,
+            record: bool, adaptive: bool, qman: bool):
+    """`_scan_kernel`'s aggregates from its final carry and the (n_steps,)
+    stacked step outputs."""
+    size = arrivals.shape[0]
+    n_bins = edges.shape[0] - 1
+    i64 = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
     a_seq, cum_seq, tdone_seq = (
         (outs[0], outs[1], outs[3]) if record else outs
     )
+    n_steps = cum_seq.shape[0]
     scalars, ad_final, qm_final = carry
     t, n_srv, n_adm, n_bat, n_eps, n_used, done, energy = scalars
 
@@ -626,6 +657,24 @@ def _scan_core(
         )
     dec_seq = outs[2] if record else None
     return (agg, (a_seq, dec_seq, lat, valid)) if record else agg
+
+
+def _scan_core(
+    table, arrivals, deadlines, phases, beliefs, draws, means, zeta, edges,
+    t0, horizon, max_eps, drain, b_max, adap=None, buffer_cap=None,
+    shed=None,
+    *, n_steps: int, record: bool, mix: bool = False, adaptive: bool = False,
+    qman: bool = False,
+):
+    """`_scan_kernel` run for a fixed ``n_steps`` steps: its aggregates
+    (and with ``record`` the per-step record).  Pure jax function."""
+    carry0, step, finish = _scan_kernel(
+        table, arrivals, deadlines, phases, beliefs, draws, means, zeta,
+        edges, t0, horizon, max_eps, drain, b_max, adap, buffer_cap, shed,
+        record=record, mix=mix, adaptive=adaptive, qman=qman,
+    )
+    carry, outs = jax.lax.scan(step, carry0, None, length=n_steps, unroll=4)
+    return finish(carry, outs)
 
 
 #: the phase_mode knob shared by simulate_compiled / run_grid / fleet:
@@ -950,19 +999,99 @@ def simulate_compiled(
     return res
 
 
+#: steps per iteration of the grid's event loop (at most; it divides the
+#: step count): the loop tests for active instances once per chunk
+_GRID_CHUNK = 256
+
+#: what the rows of the (a, consumed, t_done) step outputs that the grid's
+#: loop never reaches hold: no serve, and a resolved count past every slot,
+#: so each instance's counts stay nondecreasing for `_finish`'s search
+_UNREACHED = (0, np.iinfo(np.int32).max, np.inf)
+
+
+def _lockstep_grid(kernel_of, lanes, tables, *, n_steps: int, max_eps):
+    """Every instance of a lanes [x tables] grid, run in lockstep until
+    none is active.
+
+    ``kernel_of(lane, table)`` builds one instance's `_scan_kernel` from
+    its lane's arrays (``lanes``, a tuple of arrays with a leading lane
+    axis) and its entry of ``tables`` (None: the grid has no table axis).
+    The loop runs outside the vmap, a chunk of steps at a time, and stops
+    at the first chunk boundary where no instance is active (an instance
+    never becomes active again) or at ``n_steps``.  The rows it never
+    reaches hold `_UNREACHED`: valid slots resolve to executed steps and
+    the rest are masked, so the aggregates are bitwise those of a fixed
+    ``n_steps``-step scan per instance (`_scan_core`).  Returns them and,
+    as a (1,) array, the steps the loop ran.
+    """
+    def over_grid(f, in_axes=(), out_axes=0):
+        """``f(kernel, *xs)`` on each instance, vmapped over the grid;
+        each of ``xs`` carries the grid's axes at its entry of
+        ``in_axes``, the results at ``out_axes``."""
+        def per_lane(lane, *xs):
+            if tables is None:
+                return f(kernel_of(lane, None), *xs)
+            return jax.vmap(
+                lambda tab, *xs: f(kernel_of(lane, tab), *xs),
+                (0, *in_axes), out_axes,
+            )(tables, *xs)
+        return lambda *xs: jax.vmap(per_lane, (0, *in_axes), out_axes)(
+            lanes, *xs
+        )
+
+    chunk = math.gcd(n_steps, _GRID_CHUNK)
+    carry0 = over_grid(lambda kernel: kernel[0])()
+    run = over_grid(  # outs (chunk, S[, P])
+        lambda kernel, carry: jax.lax.scan(
+            kernel[1], carry, None, length=chunk, unroll=4
+        ),
+        (0,), (0, 1),
+    )
+    bufs0 = tuple(
+        jnp.full((n_steps, *o.shape[1:]), fill, dtype=o.dtype)
+        for o, fill in zip(jax.eval_shape(run, carry0)[1], _UNREACHED)
+    )
+
+    def any_active(state):
+        i, carry, _ = state
+        n_eps, done = carry[0][4], carry[0][6]
+        return (i < n_steps) & jnp.any(~done & (n_eps < max_eps))
+
+    def body(state):
+        i, carry, bufs = state
+        carry, outs = run(carry)
+        bufs = tuple(
+            jax.lax.dynamic_update_slice_in_dim(b, o, i, 0)
+            for b, o in zip(bufs, outs)
+        )
+        return i + chunk, carry, bufs
+
+    i, carry, bufs = jax.lax.while_loop(
+        any_active, body, (jnp.asarray(0, dtype=jnp.int32), carry0, bufs0)
+    )
+    agg = over_grid(lambda kernel, c, o: kernel[2](c, o), (0, 1))(
+        carry, bufs
+    )
+    return agg, i[None]
+
+
 @partial(jax.jit, static_argnames=("n_steps", "mix"))
 def _grid_jit(tables, arrivals, deadlines, phases, beliefs, draws, means,
               zeta, edges, t0, horizon, max_eps, drain, b_max, n_steps, mix):
-    def one(arr, dl, ph, bel, dr):
-        return jax.vmap(
-            lambda tab: _scan_core(
-                tab, arr, dl, ph, bel, dr, means, zeta, edges, t0, horizon,
-                max_eps, drain, b_max, n_steps=n_steps, record=False,
-                mix=mix,
-            )
-        )(tables)
+    def kernel_of(lane, tab):
+        arr, adm, dl, ph, bel, dr = lane
+        return _scan_kernel(
+            tab, arr, dl, ph, bel, dr, means, zeta, edges, t0, horizon,
+            max_eps, drain, b_max, record=False, mix=mix, arr_adm=adm,
+        )
 
-    return jax.vmap(one)(arrivals, deadlines, phases, beliefs, draws)
+    # masked once here: built in the loop's body, the (S, N) mask would be
+    # recomputed every chunk
+    lanes = (arrivals, _admissible(arrivals, horizon), deadlines, phases,
+             beliefs, draws)
+    return _lockstep_grid(
+        kernel_of, lanes, tables, n_steps=n_steps, max_eps=max_eps
+    )
 
 
 @partial(jax.jit, static_argnames=("n_steps", "mix"))
@@ -970,15 +1099,20 @@ def _grid_adaptive_jit(tables, arrivals, deadlines, phases, beliefs, draws,
                        means, zeta, edges, t0, horizon, max_eps, drain,
                        b_max, adap, n_steps, mix):
     # the bank stack is the whole policy axis here (the controller selects
-    # among its P entries live), so the vmap runs over trace lanes only
-    def one(arr, dl, ph, bel, dr):
-        return _scan_core(
+    # among its P entries live), so the grid has trace lanes only
+    def kernel_of(lane, _):
+        arr, adm, dl, ph, bel, dr = lane
+        return _scan_kernel(
             tables, arr, dl, ph, bel, dr, means, zeta, edges, t0, horizon,
-            max_eps, drain, b_max, adap, n_steps=n_steps, record=False,
-            mix=mix, adaptive=True,
+            max_eps, drain, b_max, adap, record=False, mix=mix,
+            adaptive=True, arr_adm=adm,
         )
 
-    return jax.vmap(one)(arrivals, deadlines, phases, beliefs, draws)
+    lanes = (arrivals, _admissible(arrivals, horizon), deadlines, phases,
+             beliefs, draws)
+    return _lockstep_grid(
+        kernel_of, lanes, None, n_steps=n_steps, max_eps=max_eps
+    )
 
 
 def run_grid(
@@ -1019,7 +1153,9 @@ def run_grid(
     One jitted dispatch returns dict of (S, P) aggregate arrays plus the
     (S, P, n_bins + 2) histogram sketch: everything a bank comparison needs
     (mean latency, power, weighted cost, sketch quantiles) without ever
-    materializing per-request data.
+    materializing per-request data.  ``steps_executed`` is the number of
+    event steps the kernel ran: it stops once every lane and table is
+    done, short of the step-count bucket it was compiled for.
     """
     with TraceAnnotation("repro.grid.prepare", lanes=len(arrivals)):
         tables = np.asarray(tables, dtype=np.int64)
@@ -1100,7 +1236,7 @@ def run_grid(
         )
     while True:
         with TraceAnnotation("repro.grid.run", steps_run=n_steps):
-            out = _grid_jit(
+            out, ran = _grid_jit(
                 *dev,
                 float(t0), np.inf if horizon is None else float(horizon),
                 max_eps, bool(drain), int(b_max), int(n_steps), mix,
@@ -1112,16 +1248,19 @@ def run_grid(
             break
         n_steps = min(2 * n_steps, cap)
     with TraceAnnotation("repro.grid.post", steps_run=n_steps) as post:
-        # the vmapped scan runs every lane and table to the longest
+        # the vmapped loop runs every lane and table in lockstep until the
+        # last is done, a chunk at a time
         used = int(np.asarray(out["n_steps_used"]).max())
-        post.set_metadata(steps_used=used)
+        executed = int(np.asarray(ran)[0])
+        post.set_metadata(steps_used=used, steps_executed=executed)
         _NSTEPS_CACHE[ck] = min(_bucket(used + 1), cap)
-        return _grid_post(out, edges, t0, zeta is not None)
+        return _grid_post(out, executed, edges, t0, zeta is not None)
 
 
-def _grid_post(out, edges, t0, have_energy):
+def _grid_post(out, executed, edges, t0, have_energy):
     """Host-side aggregate post-processing shared by the grid entries."""
     out = {k: np.asarray(v) for k, v in out.items()}
+    out["steps_executed"] = executed
     out["hist_edges"] = edges
     with np.errstate(invalid="ignore", divide="ignore"):
         span = out["t_final"] - t0
@@ -1239,7 +1378,7 @@ def run_grid_adaptive(
     )
     adap_j = lane.lowered()
     while True:
-        out = _grid_adaptive_jit(
+        out, ran = _grid_adaptive_jit(
             jnp.asarray(tables), jnp.asarray(arr), jnp.asarray(dl),
             jnp.asarray(ph), bel_j, jnp.asarray(draws), jnp.asarray(means),
             jnp.asarray(zeta_a), jnp.asarray(edges),
@@ -1252,4 +1391,6 @@ def run_grid_adaptive(
     _NSTEPS_CACHE[ck] = min(
         _bucket(int(np.asarray(out["n_steps_used"]).max()) + 1), cap
     )
-    return _grid_post(out, edges, t0, zeta is not None)
+    return _grid_post(
+        out, int(np.asarray(ran)[0]), edges, t0, zeta is not None
+    )

@@ -2,6 +2,7 @@
 Python engine per arrival mode, sketch-quantile tolerance, the vmapped
 seeds x tables grid, bank stacking, and the service-profile bank axis."""
 import dataclasses
+from functools import partial
 
 import numpy as np
 import pytest
@@ -431,6 +432,109 @@ class TestGridRunner:
         assert res.n_served == 900
         assert res.terminated
         assert len(res.batch_sizes) == 900  # b_max=1: one serve per request
+
+    @pytest.mark.parametrize(
+        "case, lens, max_epochs, dispatches",
+        [("oracle", (5, 900, 300), None, 1),
+         ("one-at-a-time", (250, 550), None, 2),
+         ("oracle", (300, 1200), 150, 1),
+         ("belief_mix", (5, 700, 400), None, 1),
+         ("adaptive", (5, 900, 300), None, 1)],
+        ids=["uneven-lanes", "escalated", "budget-cut", "belief-mix",
+             "adaptive"],
+    )
+    def test_grid_early_exit_matches_fixed_length_scan(
+        self, monkeypatch, case, lens, max_epochs, dispatches
+    ):
+        """The grid's loop stops once every instance is done; each
+        aggregate is bitwise that of `_scan_core`'s fixed scan over the
+        full step count."""
+        import jax
+
+        from repro.serving import AdaptiveController, SMDPSchedulerBank
+        from repro.serving import compiled as C
+
+        monkeypatch.setattr(C, "_NSTEPS_CACHE", {})
+        jit_name = "_grid_adaptive_jit" if case == "adaptive" else "_grid_jit"
+        grid_fn, sizes = getattr(C, jit_name), []
+
+        def spy(*args):
+            sizes.append(args[-2])  # n_steps
+            return grid_fn(*args)
+
+        monkeypatch.setattr(C, jit_name, spy)
+        rng = np.random.default_rng(5)
+        arr = pad_arrivals_batch(
+            [np.cumsum(rng.exponential(1.0 / LAM, n)) for n in lens]
+        )
+        S, N = arr.shape
+        means = np.array(
+            [0.0] + [float(SVC.mean(b)) for b in range(1, BMAX + 1)]
+        )
+        kw = dict(means=means, zeta=ENERGY, b_max=BMAX,
+                  max_epochs=max_epochs)
+        bel = np.zeros((S, 1, 1))
+        lane = None
+        if case == "adaptive":
+            bank = SMDPSchedulerBank(
+                {(0.5 * LAM,): static_policy(4, 128), (LAM,): TABLE,
+                 (2 * LAM,): static_policy(16, 128)},
+                key_names=("lam",),
+            )
+            lane = C.AdaptiveLane.from_controller(
+                AdaptiveController(bank, ewma=0.2, margin=0.1, min_dwell=5.0)
+            )
+            tables = lane.tables[None]
+            out = C.run_grid_adaptive(arr, adaptive=lane, **kw)
+            out = {k: v[:, None] if isinstance(v, np.ndarray) else v
+                   for k, v in out.items()}
+        elif case == "belief_mix":
+            tables = np.stack([
+                np.stack([TABLE, static_policy(8, 128)]),
+                np.stack([static_policy(4, 128), TABLE]),
+            ])
+            bel = rng.dirichlet(np.ones(2), (S, N))
+            out = run_grid(tables, arr, phase_mode="belief_mix",
+                           beliefs=bel, **kw)
+        else:
+            tables = (
+                np.stack([TABLE, static_policy(8, 128)])
+                if case == "oracle"  # else: serve one request per epoch
+                else np.minimum(np.arange(TABLE.shape[-1]), 1)[None]
+            )[:, None, :]
+            out = run_grid(tables, arr, **kw)
+        assert len(sizes) == dispatches
+        if max_epochs is not None:  # the budget cuts some instance short
+            assert (out["n_epochs"] == max_epochs).any()
+        n_steps = sizes[-1]
+        assert out["n_steps_used"].max() <= out["steps_executed"] < n_steps
+
+        max_eps = 2 * max(lens) + 2 if max_epochs is None else max_epochs
+        fixed = partial(
+            C._scan_core, n_steps=n_steps, record=False,
+            mix=case == "belief_mix", adaptive=lane is not None,
+        )
+        dl, ph = np.full(N, np.inf), np.zeros(N, dtype=np.int64)
+        rest = (np.ones(1), means, ENERGY, C.default_hist_edges(means), 0.0,
+                np.inf, max_eps, True, BMAX,
+                None if lane is None else lane.lowered())
+        if lane is not None:
+            # against the same lanes vmapped over the fixed scan: one lane
+            # alone may round the controller's estimate in its last bit
+            lanes = jax.jit(jax.vmap(
+                fixed, (None, 0, None, None, 0) + (None,) * 10
+            ))(tables[0], arr, dl, ph, bel, *rest)
+        for s in range(S):
+            for p, tab in enumerate(tables):
+                agg = (
+                    jax.jit(fixed)(tab, arr[s], dl, ph, bel[s], *rest)
+                    if lane is None
+                    else {k: v[s] for k, v in lanes.items()}
+                )
+                for k, v in agg.items():
+                    want, got = np.asarray(v), out[k][s, p]
+                    assert want.dtype == got.dtype, k
+                    assert want.tobytes() == got.tobytes(), (k, s, p)
 
 
 class TestSchedulerLowering:

@@ -129,6 +129,23 @@ def test_simulate_jit_2p20(one_chip):
     _fits(c)
 
 
+def test_grid_poisson(one_chip):
+    """The early-exit grid at the poisson benchmark cell's shapes: 16 lanes
+    x 1 table, 2^20 arrival slots, in the cell's 786,432-step bucket."""
+    S, n = 16, 1 << 20
+    f64, i64 = jnp.float64, jnp.int64
+    args = _shapes(
+        one_chip, ((1, 1, S_MAX + 1), i64), ((S, n), f64), ((S, n), f64),
+        ((S, n), i64), ((S, 1, 1), f64), ((S, 1), f64), ((B_MAX + 1,), f64),
+        ((B_MAX + 1,), f64), ((compiled.DEFAULT_N_BINS + 1,), f64),
+    )
+    c = compiled._grid_jit.lower(
+        *args, 0.0, np.inf, 2 * n + 2, True, B_MAX, n_steps=786432,
+        mix=False,
+    ).compile()
+    _fits(c)
+
+
 def test_fleet_jit_m8(one_chip):
     M, n = 8, 1 << 18
     f64, i64 = jnp.float64, jnp.int64

@@ -166,10 +166,10 @@ def test_simulator_spans_count_steps(tmp_path, kind):
     assert post[3]["steps_run"] == run[3]["steps_run"]
     assert post[3]["steps_run"] >= post[3]["steps_used"] > 0
     assert post[3]["steps_used"] == int(np.max(traced["n_steps_used"]))
-    if kind == "fleet":  # the fleet loop stops once no instance is active
-        assert (post[3]["steps_used"] <= post[3]["steps_executed"]
-                <= post[3]["steps_run"])
-        assert post[3]["steps_executed"] == traced["steps_executed"]
+    # both loops stop once no instance is active
+    assert (post[3]["steps_used"] <= post[3]["steps_executed"]
+            <= post[3]["steps_run"])
+    assert post[3]["steps_executed"] == traced["steps_executed"]
 
 
 def test_faulted_fleet_spans_count_faults(tmp_path):
